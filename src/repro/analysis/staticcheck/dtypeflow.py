@@ -30,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence
 
-import jax
+from jax.extend import core as jcore
 import jax.numpy as jnp
 from jax._src import source_info_util
 
@@ -51,7 +51,7 @@ def _is_int(aval) -> bool:
 
 
 def _src(eqn) -> str:
-    frame = source_info_util.user_frame(eqn.source_info)
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
     return f"{frame.file_name}:{frame.start_line}" if frame else "?"
 
 
@@ -76,9 +76,9 @@ class _Analysis:
 
 
 def _sub_jaxpr(obj):
-    if isinstance(obj, jax.core.ClosedJaxpr):
+    if isinstance(obj, jcore.ClosedJaxpr):
         return obj.jaxpr
-    if isinstance(obj, jax.core.Jaxpr):
+    if isinstance(obj, jcore.Jaxpr):
         return obj
     return None
 
@@ -92,7 +92,7 @@ def _propagate(jaxpr, taint_in: List[Optional[str]], an: _Analysis) -> List[Opti
             taint[var] = t
 
     def tget(atom) -> Optional[str]:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, jcore.Literal):
             return None  # constants are never packed planes
         return taint.get(atom)
 
@@ -187,7 +187,7 @@ def _loop_taint(eqn, in_taints: List[Optional[str]], an: _Analysis) -> List[Opti
     return _propagate(body_j, bconsts + carry, an)
 
 
-def analyze(closed: jax.core.ClosedJaxpr, cell_id: str, shape_index=None) -> List[Violation]:
+def analyze(closed: jcore.ClosedJaxpr, cell_id: str, shape_index=None) -> List[Violation]:
     """Taint-check one traced program. Sources = uint8 top-level invars;
     origins are named via the harness shape index when available."""
     jaxpr = closed.jaxpr

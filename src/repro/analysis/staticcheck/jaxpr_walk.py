@@ -21,7 +21,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterator, Optional, Tuple
 
-import jax
+from jax.extend import core as jcore
 from jax._src import source_info_util
 
 # higher-order primitive params that hold sub-jaxprs to descend into
@@ -32,7 +32,7 @@ _SUBJAXPR_KEYS = ("jaxpr", "call_jaxpr", "cond_jaxpr", "body_jaxpr", "fun_jaxpr"
 class EqnSite:
     """One equation plus where it sits in the traced program."""
 
-    eqn: jax.core.JaxprEqn
+    eqn: jcore.JaxprEqn
     stack: Tuple[str, ...]  # enclosing higher-order prims, outermost first
     repeats: int  # product of enclosing scan lengths (dynamic multiplier)
 
@@ -42,7 +42,7 @@ class EqnSite:
 
     def source(self) -> str:
         """``file:line`` of the user frame that staged this eqn (or '?')."""
-        frame = source_info_util.user_frame(self.eqn.source_info)
+        frame = source_info_util.user_frame(self.eqn.source_info.traceback)
         if frame is None:
             return "?"
         return f"{frame.file_name}:{frame.start_line}"
@@ -54,9 +54,9 @@ class EqnSite:
 
 def _as_jaxpr(obj):
     """Raw ``Jaxpr`` from a sub-jaxpr param (raw or Closed), else None."""
-    if isinstance(obj, jax.core.ClosedJaxpr):
+    if isinstance(obj, jcore.ClosedJaxpr):
         return obj.jaxpr
-    if isinstance(obj, jax.core.Jaxpr):
+    if isinstance(obj, jcore.Jaxpr):
         return obj
     return None
 

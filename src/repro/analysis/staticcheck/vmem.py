@@ -30,14 +30,14 @@ from repro.analysis.staticcheck import PassResult, Violation
 from repro.core.qtensor import QuantizedTensor
 from repro.kernels import autotune, introspect
 
-_SUBLANE, _LANE = 8, 128
+_SUBLANE = 8
 _DECODE_B = 1  # decode batch before sublane padding
 
 
 def validate_tables() -> Tuple[int, List[Violation]]:
     checked, violations = 0, []
     paths = [autotune._TABLE_PATH, autotune._user_cache_path()]
-    for path in paths:
+    for path in filter(None, paths):
         try:
             table = autotune._load_table(path)
         except ValueError as e:
@@ -50,12 +50,6 @@ def validate_tables() -> Tuple[int, List[Violation]]:
             except ValueError as e:
                 violations.append(Violation("vmem/table", path, str(e)))
     return checked, violations
-
-
-def _padded_o(o: int) -> int:
-    if any(o % c == 0 for c in autotune._CANDIDATE_O):
-        return o
-    return o + (-o % _LANE)
 
 
 def _leaf_shapes(arch: str, fmt: str, tp: int):
@@ -114,7 +108,7 @@ def sweep_configs(
                     continue
                 seen = set()
                 for k, o, q, g, where in shapes:
-                    o_pad = _padded_o(o)
+                    o_pad = autotune.padded_o(o)
                     B = _DECODE_B + (-_DECODE_B % _SUBLANE)
                     sig = (k, o_pad, q, g)
                     if sig in seen:

@@ -120,8 +120,12 @@ def _bits_step(w_g: Array, scales: Array) -> Array:
     # distance of each weight to each candidate value: (G, g, o, 2^q)
     dist = jnp.abs(w_g[..., None] - jnp.moveaxis(cand, 0, -1)[:, None, :, :])
     best = jnp.argmin(dist, axis=-1)  # (G, g, o) int
-    codes = jnp.moveaxis(patterns[best], -1, 0)  # (q, G, g, o)
-    return codes
+    # plane i is bit i of the winning pattern, built plane-major: gathering
+    # patterns[best] yields q minor-most, a layout that XLA then propagates
+    # into the (q, k, o) code buffers (~40x padding on TPU)
+    return jnp.stack(
+        [2.0 * ((best >> i) & 1).astype(w_g.dtype) - 1.0 for i in range(q)]
+    )  # (q, G, g, o)
 
 
 @functools.partial(jax.jit, static_argnames=("q", "g", "iters", "col_chunk"))

@@ -66,7 +66,6 @@ from repro.core.qtensor import QuantizedTensor
 from repro.kernels import autotune
 
 _SUBLANE = 8
-_LANE = 128
 
 
 # ---------------------------------------------------------------------------
@@ -76,9 +75,9 @@ _LANE = 128
 
 def _pad_o(packed, scales, o: int):
     """Pad the output dim to the lane block when no candidate divides it."""
-    if any(o % c == 0 for c in autotune._CANDIDATE_O):
+    pad = autotune.padded_o(o) - o
+    if not pad:
         return packed, scales, o
-    pad = -o % _LANE
     packed = jnp.pad(packed, ((0, 0), (0, 0), (0, pad)))
     scales = jnp.pad(scales, ((0, 0), (0, 0), (0, pad)))
     return packed, scales, o + pad

@@ -12,15 +12,21 @@ Resolution order for a ``(B, k, o, q, g, impl, backend)`` query:
 
 1. **in-process cache** — one dict lookup after the first query;
 2. **persisted JSON tables** — the checked-in defaults
-   (``autotune_table.json`` next to this module, common decode/config shapes)
-   and the user cache (``$REPRO_AUTOTUNE_CACHE``, default
-   ``~/.cache/repro/autotune.json``);
+   (``autotune_table.json`` next to this module) and, only when
+   ``$REPRO_AUTOTUNE_CACHE`` names one, a user table;
 3. **measurement** — unless ``REPRO_AUTOTUNE=0``, sweep the valid candidate
-   grid with synthetic inputs, pick the fastest, persist the winner;
-4. **heuristic fallback** — the old preference order (largest dividing block),
-   so unknown shapes and opted-out runs behave exactly like the pre-autotuner
-   dispatch. This is also the no-measurement answer for shapes the tables
+   grid with synthetic inputs, pick the fastest, and persist the winner to the
+   user table when one is named;
+4. **heuristic fallback** — the largest valid blocks whose VMEM estimate fits
+   the budget. This is also the no-measurement answer for shapes the tables
    don't know.
+
+Every source answers inside ONE tiling rule (:func:`candidate_blocks`): the
+blocks Mosaic accepts. A BlockSpec's last two dims must be multiples of
+(8, 128) or span the whole array, so ``block_o`` is a multiple of 128 (or all
+of ``o``) and the scales block's ``block_k // g`` rows are a multiple of 8 (or
+all ``k / g`` rows). Table entries outside the rule are refused at load, and
+interpret-mode runs use the same tiles the chip compiles.
 
 Keys deliberately include the backend (``cpu``/``tpu``/… plus ``-interpret``)
 so CPU interpret-mode timings can never masquerade as TPU schedules. The
@@ -49,9 +55,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_CANDIDATE_K = (1024, 512, 256, 128, 64)
-_CANDIDATE_O = (512, 256, 128, 64)
-_PICK_ORDER = (512, 256, 128, 64)  # legacy heuristic preference order
+_CANDIDATE_K = (1024, 512, 256, 128)
+_CANDIDATE_O = (512, 256, 128)
+_SUBLANE = 8  # second-minor tile of a Mosaic block
+_LANE = 128  # minor tile of a Mosaic block
 
 _TABLE_PATH = os.path.join(os.path.dirname(__file__), "autotune_table.json")
 
@@ -60,11 +67,10 @@ _cache: Dict[str, Tuple[int, int]] = {}
 _persisted_loaded = False
 
 
-def _user_cache_path() -> str:
-    return os.environ.get(
-        "REPRO_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro", "autotune.json"),
-    )
+def _user_cache_path() -> Optional[str]:
+    """The user table named by ``$REPRO_AUTOTUNE_CACHE``, or None (no user
+    table: nothing outside the checked-in defaults is read or written)."""
+    return os.environ.get("REPRO_AUTOTUNE_CACHE") or None
 
 
 def measurement_enabled() -> bool:
@@ -113,9 +119,9 @@ def validate_entry(key: str, blocks, *, path: str = "<in-memory>") -> Tuple[int,
     """One table entry → validated ``(block_k, block_o)``; loud on any lie.
 
     Checks, in order: key parses; blocks is a pair of positive ints; the
-    blocks satisfy the kernels' divisibility contract (``_valid_bk`` +
-    ``o % block_o``); and — for real-hardware backends only (interpret-mode
-    entries have no VMEM) — the impl's registered per-grid-step estimate
+    blocks satisfy the tiling rule (``_valid_bk`` + ``_valid_bo``); and —
+    for real-hardware backends only (interpret-mode entries have no VMEM) —
+    the impl's registered per-grid-step estimate
     fits :func:`repro.kernels.introspect.vmem_budget`. Impls with no
     registered estimator skip the budget check (unpriceable ≠ invalid).
     """
@@ -130,11 +136,13 @@ def validate_entry(key: str, blocks, *, path: str = "<in-memory>") -> Tuple[int,
             "must be a [block_k, block_o] pair of positive ints"
         )
     bk, bo = blocks
-    if not _valid_bk(bk, k, g) or o % bo:
+    if not _valid_bk(bk, k, g) or not _valid_bo(bo, o):
         raise ValueError(
             f"autotune table {path}: entry {key!r} blocks ({bk}, {bo}) violate "
-            f"the tiling contract (k={k} % block_k == 0, o={o} % block_o == 0, "
-            f"block_k % g == 0 or g % block_k == 0 with g={g})"
+            f"the tiling contract (k={k} % block_k == 0, block_k % g == 0 or "
+            f"g % block_k == 0 with g={g}, scales rows block_k//g a multiple "
+            f"of {_SUBLANE} or all k/g; o={o} % block_o == 0 with block_o a "
+            f"multiple of {_LANE} or all of o)"
         )
     if not backend.endswith("-interpret"):
         from repro.kernels import introspect
@@ -180,12 +188,14 @@ def _ensure_persisted_loaded() -> None:
     global _persisted_loaded
     if _persisted_loaded:
         return
-    # user cache wins over checked-in defaults: it was measured on this host
+    # a named user table wins over checked-in defaults: it was measured there
     merged = _load_table(_TABLE_PATH)
     validate_table(merged, path=_TABLE_PATH)
-    user = _load_table(_user_cache_path())
-    validate_table(user, path=_user_cache_path())
-    merged.update(user)
+    user_path = _user_cache_path()
+    if user_path is not None:
+        user = _load_table(user_path)
+        validate_table(user, path=user_path)
+        merged.update(user)
     for key, blocks in merged.items():
         _cache.setdefault(key, blocks)
     _persisted_loaded = True
@@ -193,17 +203,16 @@ def _ensure_persisted_loaded() -> None:
 
 def _persist(key: str, blocks: Tuple[int, int]) -> None:
     path = _user_cache_path()
+    if path is None:
+        return  # no user table named: the in-process cache holds the winner
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        try:
-            table = _load_table(path)
-        except ValueError:
-            table = {}  # corrupt user cache: start over rather than refuse to tune
-        table[key] = blocks
-        with open(path, "w") as f:
-            json.dump({k: list(v) for k, v in sorted(table.items())}, f, indent=1)
-    except OSError:
-        pass  # read-only filesystem: in-process cache still holds the winner
+        table = _load_table(path)
+    except ValueError:
+        table = {}  # corrupt user cache: start over rather than refuse to tune
+    table[key] = blocks
+    with open(path, "w") as f:
+        json.dump({k: list(v) for k, v in sorted(table.items())}, f, indent=1)
 
 
 def clear_cache() -> None:
@@ -219,27 +228,57 @@ def clear_cache() -> None:
 
 
 def _valid_bk(c: int, k: int, g: int) -> bool:
-    return k % c == 0 and (c % g == 0 or g % c == 0)
+    """``block_k = c`` divides k, composes with g, and gives a scales block
+    Mosaic accepts: ``c // g`` rows (1 when a group spans blocks) that are a
+    multiple of the sublane tile or the whole ``k / g`` axis."""
+    if k % c or not (c % g == 0 or g % c == 0):
+        return False
+    rows = max(c // g, 1)
+    return rows % _SUBLANE == 0 or rows == k // g
+
+
+def _valid_bo(c: int, o: int) -> bool:
+    """``block_o = c`` divides o and is lane-aligned or the whole of o."""
+    return o % c == 0 and (c % _LANE == 0 or c == o)
+
+
+def padded_o(o: int) -> int:
+    """The output dim the Pallas wrappers dispatch: o itself when a candidate
+    block divides it, else o rounded up to the lane width."""
+    if any(_valid_bo(c, o) for c in _CANDIDATE_O):
+        return o
+    return o + (-o % _LANE)
 
 
 def candidate_blocks(k: int, o: int, g: int) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
-    """Valid (block_k, block_o) candidate axes for a padded (k, o, g)."""
-    bks = tuple(c for c in _CANDIDATE_K if _valid_bk(c, k, g))
-    if not bks:
-        # irregular group size (e.g. g=96): fall back to g-aligned blocks
-        bks = tuple(m * g for m in (8, 4, 2, 1) if m * g <= k and k % (m * g) == 0)
-    bos = tuple(c for c in _CANDIDATE_O if o % c == 0)
+    """The tiling rule: valid (block_k, block_o) axes, largest first, for a
+    padded (k, o, g). When no candidate block_k is valid (e.g. k=768 at
+    g=128: 6 scale rows), the whole k is, since it spans all k/g rows."""
+    bks = tuple(c for c in _CANDIDATE_K if _valid_bk(c, k, g)) or (k,)
+    bos = tuple(c for c in _CANDIDATE_O if _valid_bo(c, o))
     return bks, bos
 
 
-def heuristic_blocks(k: int, o: int, g: int) -> Tuple[int, int]:
-    """The pre-autotuner choice: largest preference-ordered dividing block."""
-    bk = next((c for c in _PICK_ORDER if k % c == 0 and _valid_bk(c, k, g)), 0)
-    if not bk:
-        bks, _ = candidate_blocks(k, o, g)
-        bk = bks[0] if bks else 0
-    bo = next((c for c in _PICK_ORDER if o % c == 0), 0)
-    return bk, bo
+def _fits(impl: Optional[str], B: int, bk: int, bo: int, q: int, g: int) -> bool:
+    from repro.kernels import introspect
+
+    try:
+        return introspect.fits_budget(impl, B=B, block_k=bk, block_o=bo, q=q, g=g)
+    except KeyError:
+        return True  # no estimator registered: unpriceable, not refused
+
+
+def heuristic_blocks(
+    k: int, o: int, g: int, *, B: int = 8, q: int = 1, impl: Optional[str] = None
+) -> Tuple[int, int]:
+    """The largest valid pair (block_k first) whose VMEM estimate fits the
+    budget; the smallest valid pair when none does; ``(0, 0)`` when o has no
+    valid block."""
+    bks, bos = candidate_blocks(k, o, g)
+    if not bos:
+        return 0, 0
+    pairs = [(bk, bo) for bk in bks for bo in bos]
+    return next((p for p in pairs if _fits(impl, B, *p, q, g)), pairs[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -308,32 +347,34 @@ def _measure(
     if entry is None:
         return None  # unknown impl: caller falls through to the heuristic
     bks, bos = candidate_blocks(k, o, g)
-    if not bks or not bos:
+    # keep the sweep bounded: the 3 largest of each axis cover the useful
+    # range; on hardware, only pairs the VMEM budget admits
+    pairs = [
+        (bk, bo)
+        for bk in bks[:3]
+        for bo in bos[:3]
+        if interpret or _fits(impl, B, bk, bo, q, g)
+    ]
+    if not pairs:
         return None
-    # keep the sweep bounded: the 3 largest of each axis cover the useful range
-    bks, bos = bks[:3], bos[:3]
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((B, k)), jnp.float32)
     packed = jnp.asarray(rng.integers(0, 256, (q, k // 8, o)), jnp.uint8)
     scales = jnp.asarray(entry[1](rng, q, k, o, g), jnp.float32)
     fn = entry[0]()
 
+    # every pair obeys the tiling rule, so a candidate that fails to compile
+    # is a kernel bug: it raises instead of being skipped in silence
     best, best_t = None, float("inf")
-    for bk in bks:
-        for bo in bos:
-            try:
-                t = _time_once(
-                    functools.partial(
-                        fn, g=g, block_k=bk, block_o=bo, interpret=interpret
-                    ),
-                    x,
-                    packed,
-                    scales,
-                )
-            except Exception:
-                continue  # candidate doesn't compile/fit — skip it
-            if t < best_t:
-                best, best_t = (bk, bo), t
+    for bk, bo in pairs:
+        t = _time_once(
+            functools.partial(fn, g=g, block_k=bk, block_o=bo, interpret=interpret),
+            x,
+            packed,
+            scales,
+        )
+        if t < best_t:
+            best, best_t = (bk, bo), t
     return best
 
 
@@ -356,14 +397,14 @@ def get_blocks(
     """Best known (block_k, block_o) for a padded kernel shape.
 
     Never raises on unknown shapes: resolution falls through cache → tables →
-    measurement (when enabled) → the legacy heuristic. Returns ``(0, 0)`` only
-    when no valid tiling exists at all (caller decides how to pad or fail).
+    measurement (when enabled) → the heuristic. Returns ``(0, 0)`` only when
+    no valid tiling exists at all (caller decides how to pad or fail).
     """
     _ensure_persisted_loaded()
     backend = backend_tag(interpret)
     key = make_key(B, k, o, q, g, impl, backend)
     hit = _cache.get(key)
-    if hit is not None and _valid_bk(hit[0], k, g) and o % hit[1] == 0:
+    if hit is not None and _valid_bk(hit[0], k, g) and _valid_bo(hit[1], o):
         return hit
 
     if allow_measure is None:
@@ -375,7 +416,7 @@ def get_blocks(
             _persist(key, measured)
             return measured
 
-    blocks = heuristic_blocks(k, o, g)
+    blocks = heuristic_blocks(k, o, g, B=B, q=q, impl=impl)
     if blocks[0] and blocks[1]:
         _cache[key] = blocks  # memoise so the divisibility scan runs once
     return blocks

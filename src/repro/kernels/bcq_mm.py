@@ -57,10 +57,12 @@ def vmem_bytes(*, B: int, block_k: int, block_o: int, q: int, g: int) -> int:
 
 
 def _unpack_block(packed: jax.Array, compute_dtype) -> jax.Array:
-    """uint8 (q, bk/8, bo) → ±1 (q, bk, bo) in compute_dtype (VPU shift/mask)."""
+    """uint8 (q, bk/8, bo) → ±1 (q, bk, bo) in compute_dtype (VPU shift/mask).
+
+    The bytes are widened to int32 first: Mosaic has no uint8 → float cast."""
     q, kc, bo = packed.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (1, 1, 8, 1), 2)
-    bits = (packed[:, :, None, :] >> shifts) & jnp.uint8(1)  # (q, kc, 8, bo)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8, 1), 2)
+    bits = (packed.astype(jnp.int32)[:, :, None, :] >> shifts) & 1  # (q, kc, 8, bo)
     signs = 2.0 * bits.astype(compute_dtype) - 1.0
     return signs.reshape(q, kc * 8, bo)
 
@@ -146,7 +148,7 @@ def bcq_mm_call(
         out_specs=pl.BlockSpec((B, block_o), lambda io, ik: (0, io)),
         out_shape=jax.ShapeDtypeStruct((B, o), jnp.float32),
         scratch_shapes=[pltpu.VMEM((B, block_o), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

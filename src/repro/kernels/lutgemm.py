@@ -157,7 +157,7 @@ def lutgemm(
         out_specs=pl.BlockSpec((B, block_o), lambda io, ik: (0, io)),
         out_shape=jax.ShapeDtypeStruct((B, o), jnp.float32),
         scratch_shapes=[pltpu.VMEM((B, block_o), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -64,8 +64,9 @@ def vmem_bytes(*, B: int, block_k: int, block_o: int, q: int, g: int) -> int:
 def _decode_ternary_block(packed: jax.Array, compute_dtype) -> jax.Array:
     """uint8 (2, bk/8, bo) sign+mask planes → t ∈ {-1, 0, +1} (bk, bo)."""
     _, kc, bo = packed.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (1, 1, 8, 1), 2)
-    bits = (packed[:, :, None, :] >> shifts) & jnp.uint8(1)  # (2, kc, 8, bo)
+    # widen to int32 first: Mosaic has no uint8 -> float cast
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8, 1), 2)
+    bits = (packed.astype(jnp.int32)[:, :, None, :] >> shifts) & 1  # (2, kc, 8, bo)
     planes = bits.reshape(PLANES, kc * 8, bo).astype(compute_dtype)
     sign = 2.0 * planes[0] - 1.0
     return sign * planes[1]  # mask=0 zeroes the code
@@ -148,7 +149,7 @@ def ternary_mm_call(
         out_specs=pl.BlockSpec((B, block_o), lambda io, ik: (0, io)),
         out_shape=jax.ShapeDtypeStruct((B, o), jnp.float32),
         scratch_shapes=[pltpu.VMEM((B, block_o), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

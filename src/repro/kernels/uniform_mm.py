@@ -53,8 +53,9 @@ def vmem_bytes(*, B: int, block_k: int, block_o: int, q: int, g: int) -> int:
 def _unpack_codes_block(packed: jax.Array, compute_dtype) -> jax.Array:
     """uint8 (q, bk/8, bo) bit planes → codes (bk, bo) in compute_dtype."""
     q, kc, bo = packed.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (1, 1, 8, 1), 2)
-    bits = (packed[:, :, None, :] >> shifts) & jnp.uint8(1)  # (q, kc, 8, bo)
+    # widen to int32 first: Mosaic has no uint8 -> float cast
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8, 1), 2)
+    bits = (packed.astype(jnp.int32)[:, :, None, :] >> shifts) & 1  # (q, kc, 8, bo)
     planes = bits.reshape(q, kc * 8, bo).astype(compute_dtype)
     # q is static (<= 8): unroll the weighted plane sum with Python scalar
     # weights 2^i — Pallas kernels may not capture array constants
@@ -138,7 +139,7 @@ def uniform_mm_call(
         out_specs=pl.BlockSpec((B, block_o), lambda io, ik: (0, io)),
         out_shape=jax.ShapeDtypeStruct((B, o), jnp.float32),
         scratch_shapes=[pltpu.VMEM((B, block_o), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -11,27 +11,15 @@ import contextlib
 
 import jax
 
+from repro.parallel.compat import auto_mesh
 from repro.parallel.sharding import MeshAxes, multi_pod_axes, single_pod_axes
 
 
 @contextlib.contextmanager
 def set_mesh(mesh):
-    """Activate ``mesh`` as the ambient mesh, across JAX generations.
-
-    Newest JAX spells this ``jax.set_mesh``; before that ``jax.sharding
-    .use_mesh``; older releases enter the ``Mesh`` object itself as a context
-    manager (which populates the thread-local resource env that
-    :func:`repro.parallel.compat.get_abstract_mesh` reads back). All mesh
-    activation in this repo goes through here — never call the jax API
-    directly.
-    """
-    if hasattr(jax, "set_mesh"):
-        ctx = jax.set_mesh(mesh)
-    elif hasattr(jax.sharding, "use_mesh"):
-        ctx = jax.sharding.use_mesh(mesh)
-    else:
-        ctx = mesh
-    with ctx:
+    """Activate ``mesh`` as the ambient mesh (``jax.set_mesh``). All mesh
+    activation in this repo goes through here."""
+    with jax.set_mesh(mesh):
         yield mesh
 
 
@@ -39,7 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def production_axes(*, multi_pod: bool = False) -> MeshAxes:
@@ -49,4 +37,4 @@ def production_axes(*, multi_pod: bool = False) -> MeshAxes:
 def make_mesh_from_axes(ax: MeshAxes):
     names = tuple(n for n, _ in ax.sizes)
     shape = tuple(s for _, s in ax.sizes)
-    return jax.make_mesh(shape, names)
+    return auto_mesh(shape, names)

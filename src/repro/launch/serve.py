@@ -38,6 +38,10 @@ opts in). Needs a truncation-capable format (``supports_truncate`` in the
 registry — ``bcq`` and ``ternary``); the launcher checks the capability flag,
 not a name list.
 
+The CLI serves a float32 reduction of the arch sized for a CPU host;
+``chip_smoke.py`` at the repo root drives the same build path
+(:func:`build_engine`) with a published config on the chip.
+
 ``--tp N`` serves tensor-parallel (DESIGN.md §7): weights are placed
 column/row-parallel over an N-way ``model`` mesh under ``shard_map``, KV
 caches shard their kv-head dim, and greedy tokens stay identical to the
@@ -68,8 +72,43 @@ from repro.configs import ARCH_IDS, get_config
 from repro.core.formats import format_names, get_format
 from repro.data import MarkovCorpus
 from repro.infer import Engine, Request, Scheduler, SpecConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, reduced
 from repro.quant import QuantPolicy, quantize_params, quantized_bytes
+
+
+def serving_config(arch: str):
+    """The configuration the serving CLIs build: a float32 reduction of the
+    arch sized for a CPU host, with >=128-dim linears so quantization still
+    bites."""
+    base = get_config(arch)
+    return reduced(base, d_model=256, n_kv_heads=4,
+                   d_ff=512 if base.d_ff else 0,
+                   moe_d_ff=256 if base.n_experts else None)
+
+
+def build_params(cfg, *, q: int, g: int = 128, fmt: str = "bcq",
+                 iters: int = 4, seed: int = 0):
+    """Random weights from ``seed``, quantized with ``(q, g, fmt)`` (``q=0``
+    keeps them dense).
+
+    Init and quantization run as ONE jitted program, so XLA frees each dense
+    layer stack as soon as it is packed: the build peak stays near the
+    quantized model plus one stack's float32 working copy, instead of the
+    whole dense model plus that copy."""
+    key = jax.random.PRNGKey(seed)
+    if not q:
+        return init_params(key, cfg)
+    policy = QuantPolicy(q=q, g=g, iters=iters, fmt=fmt)
+    return jax.jit(lambda k: quantize_params(init_params(k, cfg), policy))(key)
+
+
+def build_engine(cfg, *, q: int, g: int = 128, fmt: str = "bcq",
+                 iters: int = 4, seed: int = 0, **engine_kw) -> Engine:
+    """The serving build path: config → weights → quantization → Engine
+    (which fuses QKV/gate-up). ``engine_kw`` goes to :class:`Engine`."""
+    return Engine(cfg, build_params(cfg, q=q, g=g, fmt=fmt, iters=iters,
+                                    seed=seed), **engine_kw)
 
 
 def build_requests(cfg, n, prompt_len, gen, *, mixed_temperature=True, seed=3,
@@ -147,6 +186,11 @@ def drive_continuous(engine, reqs, arrivals, *, n_slots, chunk, speculate=None,
             sched.submit(reqs[i])
             i += 1
         if sched.idle:
+            if i == len(reqs):
+                ended = {r: (o.state.name, o.reason) for r, o in sched.outcomes.items()
+                         if o.state.name != "FINISHED"}
+                raise RuntimeError(f"{len(reqs) - len(done)} requests ended "
+                                   f"without completing: {ended}")
             # nothing in flight: sleep until the next arrival
             time.sleep(max(0.0, arrivals[i] - now))
             continue
@@ -257,21 +301,11 @@ def main() -> None:
         ap.error("--prefix-cache-mb/--prefill-chunk drive the scheduler; "
                  "they cannot be combined with --sequential")
 
-    # reduced config sized so quantization actually bites (>=128-dim linears)
-    cfg = reduced(get_config(args.arch), d_model=256, n_kv_heads=4,
-                  d_ff=512 if get_config(args.arch).d_ff else 0,
-                  moe_d_ff=256 if get_config(args.arch).n_experts else None)
+    cfg = serving_config(args.arch)
     if cfg.input_kind != "tokens":
         ap.error(f"{args.arch} is a modality-stub arch; see examples/ for the "
                  "embedding-input serving path")
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    print(f"dense bytes: {quantized_bytes(params)/2**20:.2f} MiB")
-    if args.q:
-        params = quantize_params(
-            params, QuantPolicy(q=args.q, g=args.g, iters=4, fmt=args.format)
-        )
-        print(f"{args.format} q={args.q} g={args.g}: "
-              f"{quantized_bytes(params)/2**20:.2f} MiB")
+    enable_compile_cache()
 
     mesh = None
     if args.tp > 1:
@@ -301,10 +335,12 @@ def main() -> None:
             block_tokens=args.prefix_block,
             max_bytes=args.prefix_cache_mb << 20,
         )
-    engine = Engine(cfg, params, mesh=mesh,
-                    max_seq=args.prompt_len + args.gen + 8 + headroom,
-                    tracer=tracer, prefix_cache=prefix_cache)
-    del params  # the engine holds the fused layout; free the unfused tree
+    engine = build_engine(cfg, q=args.q, g=args.g, fmt=args.format, mesh=mesh,
+                          max_seq=args.prompt_len + args.gen + 8 + headroom,
+                          tracer=tracer, prefix_cache=prefix_cache)
+    label = f"{args.format} q={args.q} g={args.g}" if args.q else "dense"
+    print(f"{cfg.name} {label}: "
+          f"{quantized_bytes(engine.params)/2**20:.2f} MiB of weights")
     reqs = build_requests(cfg, args.requests, args.prompt_len, args.gen,
                           shared_prefix_len=args.shared_prefix_len)
     arrivals = poisson_arrivals(args.requests, args.rate, seed=1)

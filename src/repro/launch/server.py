@@ -606,12 +606,10 @@ def bound_port(runner: "web.AppRunner") -> int:
 def main() -> None:  # pragma: no cover - CLI wrapper over tested pieces
     import argparse
 
-    import jax
-
-    from repro.configs import ARCH_IDS, get_config
+    from repro.configs import ARCH_IDS
     from repro.infer import SpecConfig
-    from repro.models import init_params, reduced
-    from repro.quant import QuantPolicy, quantize_params
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.serve import build_engine, serving_config
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3.2-3b")
@@ -632,20 +630,18 @@ def main() -> None:  # pragma: no cover - CLI wrapper over tested pieces
     _require_aiohttp()
 
     spec = SpecConfig.parse(args.speculate) if args.speculate else None
-    cfg = reduced(get_config(args.arch), d_model=256, n_kv_heads=4,
-                  d_ff=512 if get_config(args.arch).d_ff else 0)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    if args.q:
-        params = quantize_params(params, QuantPolicy(q=args.q, g=args.g, iters=4))
+    cfg = serving_config(args.arch)
+    enable_compile_cache()
     engine_max_seq = args.max_seq + (spec.gamma + 1 if spec else 0)
-    from repro.infer import Engine, PrefixCache
+    from repro.infer import PrefixCache
 
     pc = (
         PrefixCache(max_bytes=args.prefix_cache_mb << 20)
         if args.prefix_cache_mb > 0
         else None
     )
-    engine = Engine(cfg, params, max_seq=engine_max_seq, prefix_cache=pc)
+    engine = build_engine(cfg, q=args.q, g=args.g, max_seq=engine_max_seq,
+                          prefix_cache=pc)
 
     async def serve():
         session = ServeSession(

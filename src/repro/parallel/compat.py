@@ -1,72 +1,51 @@
-"""Version-guarded JAX API compatibility shims.
+"""Thin spellings of the JAX distribution API used across the repo.
 
-The distribution layer targets the *current* JAX surface (``jax.shard_map``,
-``jax.sharding.get_abstract_mesh``, ``Mesh.axis_sizes``) but must run on older
-releases where those live under different names (``jax.experimental.shard_map``
-with ``check_rep``, thread-local physical mesh, ``Mesh.shape``). Every module
-that shard_maps or inspects the ambient mesh imports from here instead of
-version-guarding call sites one by one.
+Every module that shard_maps or inspects the ambient mesh imports from here,
+so a future API rename is one edit.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 
-__all__ = ["shard_map", "get_abstract_mesh", "mesh_axis_names_sizes", "axis_size"]
+__all__ = [
+    "auto_mesh", "shard_map", "get_abstract_mesh", "mesh_axis_names_sizes",
+    "axis_size",
+]
+
+
+def auto_mesh(shape, names):
+    """``jax.make_mesh`` with ``Auto`` axes. JAX's default is ``Explicit``
+    axes, under which ``with_sharding_constraint`` (``parallel/ctx.py``)
+    asserts a sharding instead of imposing it."""
+    return jax.make_mesh(
+        shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names)
+    )
 
 
 def axis_size(axis_name) -> jax.Array:
-    """Size of a mapped mesh axis from inside shard_map: ``jax.lax.axis_size``
-    where it exists, else the classic ``psum(1, axis)`` idiom."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a mapped mesh axis from inside shard_map."""
+    return jax.lax.axis_size(axis_name)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` when available, else the ``jax.experimental`` spelling.
-
-    The replication-checking kwarg was renamed ``check_rep`` → ``check_vma``;
-    callers use the new name and we translate downward.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+    """``jax.shard_map``."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
     )
 
 
 def get_abstract_mesh():
     """The ambient mesh (entered via :func:`repro.launch.mesh.set_mesh`), or
-    ``None`` when no mesh is active.
-
-    New JAX exposes ``jax.sharding.get_abstract_mesh``; on older releases the
-    active mesh lives in the thread-local resource env that ``with mesh:``
-    populates.
-    """
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not getattr(mesh, "axis_names", None):
-            return None
-        return mesh
-    from jax._src import mesh as _mesh_lib
-
-    mesh = _mesh_lib.thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty:
+    ``None`` when no mesh is active."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not getattr(mesh, "axis_names", None):
         return None
     return mesh
 
 
 def mesh_axis_names_sizes(mesh) -> Tuple[Tuple[str, ...], Tuple[int, ...]]:
-    """``(axis_names, axis_sizes)`` across Mesh/AbstractMesh generations
-    (``axis_sizes`` predates only the newest API; ``shape`` is the old dict)."""
-    names = tuple(mesh.axis_names)
-    if hasattr(mesh, "axis_sizes"):
-        return names, tuple(mesh.axis_sizes)
-    return names, tuple(mesh.shape[n] for n in names)
+    """``(axis_names, axis_sizes)`` of a Mesh or AbstractMesh."""
+    return tuple(mesh.axis_names), tuple(mesh.axis_sizes)

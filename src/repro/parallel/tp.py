@@ -61,7 +61,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.formats import get_format
 from repro.core.qtensor import QuantizedTensor
 from repro.models.config import ModelConfig
-from repro.parallel.compat import mesh_axis_names_sizes, shard_map
+from repro.parallel.compat import auto_mesh, mesh_axis_names_sizes, shard_map
 from repro.parallel.ctx import tp_shard_region
 from repro.parallel.sharding import MeshAxes, cache_specs
 
@@ -100,7 +100,7 @@ def make_tp_mesh(tp: int, axis: str = "model"):
             f"on a CPU host set XLA_FLAGS=--xla_force_host_platform_device_"
             f"count={tp} before the first jax call"
         )
-    return jax.make_mesh((tp,), (axis,))
+    return auto_mesh((tp,), (axis,))
 
 
 # ---------------------------------------------------------------------------

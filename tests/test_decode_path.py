@@ -217,6 +217,47 @@ def test_autotune_candidates_respect_group_size():
     assert all(512 % c == 0 for c in bos)
 
 
+@pytest.mark.parametrize(
+    "k,o,g,B,want",
+    [
+        (3072, 5120, 128, 8, (1024, 512)),  # Phi-4-mini fused QKV, decode
+        (3072, 200064, 128, 8, (1024, 128)),  # 200k-vocab head: 128 | o only
+        (768, 3072, 128, 8, (768, 512)),  # TP-local wo: 6 scale rows → all of k
+        (1024, 256, 64, 8, (1024, 256)),
+    ],
+)
+def test_autotune_rule_yields_mosaic_tiles(k, o, g, B, want):
+    """Every candidate obeys Mosaic's block rule: scale rows block_k//g a
+    multiple of 8 or all k/g rows, block_o a multiple of 128 or all of o."""
+    bks, bos = autotune.candidate_blocks(k, o, g)
+    for bk in bks:
+        rows = max(bk // g, 1)
+        assert k % bk == 0 and (rows % 8 == 0 or rows == k // g)
+    assert all(o % bo == 0 and (bo % 128 == 0 or bo == o) for bo in bos)
+    assert autotune.heuristic_blocks(k, o, g, B=B, q=3, impl="bcq_mm") == want
+
+
+def test_autotune_measure_raises_on_kernel_error(tuner, monkeypatch):
+    """A candidate that fails is a kernel bug under the tiling rule: the
+    sweep re-raises it instead of skipping to another candidate."""
+    def broken(*args, **kw):
+        raise RuntimeError("Mosaic refused the block")
+
+    monkeypatch.setitem(
+        tuner._MEASURE_KERNELS, "bcq_mm",
+        (lambda: broken, tuner._MEASURE_KERNELS["bcq_mm"][1]),
+    )
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        tuner.get_blocks(B=8, k=512, o=256, q=2, g=64, impl="bcq_mm",
+                         interpret=True)
+
+
+def test_autotune_reads_no_user_table_unless_named(tuner, monkeypatch):
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE")
+    assert tuner._user_cache_path() is None
+    tuner._persist("bcq_mm/cpu/B8/k512/o256/q2/g64", (512, 256))  # no-op
+
+
 def test_quantized_matmul_uses_autotuned_blocks(tuner, rng):
     """End-to-end: wrapper dispatch through the tuner still matches the oracle."""
     w = jnp.asarray(rng.standard_normal((768, 200)), jnp.float32)

@@ -211,8 +211,12 @@ def test_vmem_estimators_registered():
 
 
 def test_autotune_validate_entry_errors():
-    ok = autotune.validate_entry("bcq_mm/cpu-interpret/B8/k768/o256/q3/g96", [768, 64])
-    assert ok == (768, 64)
+    ok = autotune.validate_entry("bcq_mm/cpu-interpret/B8/k768/o256/q3/g96", [768, 128])
+    assert ok == (768, 128)
+    with pytest.raises(ValueError, match="tiling contract"):  # not lane-aligned
+        autotune.validate_entry("bcq_mm/cpu-interpret/B8/k768/o256/q3/g96", [768, 64])
+    with pytest.raises(ValueError, match="tiling contract"):  # 4 scale rows
+        autotune.validate_entry("bcq_mm/tpu/B8/k1024/o256/q3/g128", [512, 256])
     with pytest.raises(ValueError, match="expected impl/backend"):
         autotune.validate_entry("bcq_mm/cpu/B8/k768", [512, 256])
     with pytest.raises(ValueError, match="not g<int>"):
