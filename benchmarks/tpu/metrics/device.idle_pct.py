@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+``1 - union(device op intervals) / window``."""
+
+from tpubench import trace
+
+
+def read(run):
+    if run.trace is None or run.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - trace.busy_s(run.trace) / run.trace.window_s)
