@@ -39,18 +39,7 @@ ISSUE 10 adds the prefix-cache / chunked-prefill regimes (DESIGN.md §12):
   queue, cancels and deadlines: the refcount ledger must drain to zero and
   ``hits + misses == commits + aborts`` (leak-free accounting, asserted).
 
-ISSUE 8 adds the observability overhead regime (``BENCH_obs.json``): the
-same burst workload served with the tracer + metrics registry attached vs
-bare, interleaved and min-of-N so the delta is the instrumentation and not
-host noise, plus the raw per-span record cost and the instrumented run's
-full metrics snapshot (the artifact a dashboard would scrape). The
-documented budget — single-digit µs per span, serving overhead within noise
-— is *asserted* in tests/test_obs.py; here it is measured and reported.
-
 PYTHONPATH=src python benchmarks/serve_bench.py [--out BENCH_serve.json]
-                                                [--obs-out BENCH_obs.json]
-PYTHONPATH=src python benchmarks/serve_bench.py --obs-only   # just the obs
-                                                             # artifact
 """
 
 from __future__ import annotations
@@ -223,7 +212,7 @@ def prefix_bench(rows) -> None:
     # warmups: compile both engines' shapes; the warm engine's pass also
     # commits the shared prefix, which is exactly the steady state measured.
     # Measured runs interleave cold/warm and take the min-of-N mean so host
-    # load drift lands on both sides (same policy as obs_bench).
+    # load drift lands on both sides.
     serve(cold_eng, 0)
     serve(warm_eng, 0)
     cold_runs, warm_runs = [], []
@@ -360,101 +349,11 @@ def prefix_bench(rows) -> None:
     )
 
 
-def obs_bench(cfg, engine, out_path) -> None:
-    """ISSUE 8 artifact: tracer on/off serving overhead + per-span record
-    cost + the instrumented run's metrics snapshot, written to
-    ``BENCH_obs.json``. Bare and instrumented runs are interleaved and the
-    min of N is compared, so host-load drift lands on both sides; the hard
-    *assertion* of the per-span budget lives in tests/test_obs.py —
-    this just measures and reports it on real serving."""
-    from repro.obs import MetricsRegistry, Tracer
-
-    zeros = np.zeros(N_REQUESTS)
-    repeats = 3
-    offs, ons = [], []
-    tracer = registry = None
-    for _ in range(repeats):
-        _, _, dt_off = drive_continuous(
-            engine, build_requests(cfg, N_REQUESTS, PROMPT_LEN, GEN), zeros,
-            n_slots=4, chunk=CHUNK,
-        )
-        offs.append(dt_off)
-        tracer, registry = Tracer(capacity=1 << 16), MetricsRegistry()
-        _, _, dt_on = drive_continuous(
-            engine, build_requests(cfg, N_REQUESTS, PROMPT_LEN, GEN), zeros,
-            n_slots=4, chunk=CHUNK, tracer=tracer, metrics=registry,
-        )
-        ons.append(dt_on)
-    off, on = min(offs), min(ons)
-    overhead_pct = 100.0 * (on - off) / off
-    total_new = N_REQUESTS * GEN
-    st = tracer.stats()
-
-    # Raw span-record cost, isolated from serving: the budget documented in
-    # DESIGN.md §11 and asserted (<100us with wide slack) in test_obs.py.
-    probe, n_spans = Tracer(capacity=1 << 17), 20000
-    t0 = time.perf_counter()
-    for _ in range(n_spans):
-        with probe.span("bench", lane="bench"):
-            pass
-    per_span_us = (time.perf_counter() - t0) / n_spans * 1e6
-
-    rows = [
-        {
-            "name": "obs/continuous_slots4/burst_tracer_off",
-            "tokens_per_s": round(total_new / off, 2),
-            "makespan_s": round(off, 3),
-            "derived": f"requests={N_REQUESTS};gen={GEN};chunk={CHUNK};"
-            f"min_of={repeats}",
-        },
-        {
-            "name": "obs/continuous_slots4/burst_tracer_on",
-            "tokens_per_s": round(total_new / on, 2),
-            "makespan_s": round(on, 3),
-            "derived": f"requests={N_REQUESTS};gen={GEN};chunk={CHUNK};"
-            f"min_of={repeats};events={st['buffered']};evicted={st['evicted']}",
-        },
-        {
-            "name": "obs/overhead_tracer_plus_metrics",
-            "tokens_per_s": None,
-            "makespan_s": None,
-            "derived": f"overhead_pct={overhead_pct:.2f};"
-            f"events_per_run={st['recorded']}",
-        },
-        {
-            "name": "obs/span_record_cost",
-            "tokens_per_s": None,
-            "makespan_s": None,
-            "derived": f"per_span_us={per_span_us:.2f};n_spans={n_spans};"
-            "budget_us=100 (asserted in tests/test_obs.py)",
-        },
-    ]
-    print(f"obs: tracer off {off:.2f}s / on {on:.2f}s "
-          f"({overhead_pct:+.2f}%), {per_span_us:.2f}us/span")
-    out = os.path.abspath(out_path)
-    with open(out, "w") as f:
-        json.dump(
-            {"rows": rows, "metrics_snapshot": registry.snapshot()},
-            f, indent=1, sort_keys=True,
-        )
-        f.write("\n")
-    print("wrote", out)
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--out",
         default=os.path.join(os.path.dirname(__file__), "..", "BENCH_serve.json"),
-    )
-    ap.add_argument(
-        "--obs-out",
-        default=os.path.join(os.path.dirname(__file__), "..", "BENCH_obs.json"),
-    )
-    ap.add_argument(
-        "--obs-only",
-        action="store_true",
-        help="skip the serving regimes; only produce the BENCH_obs.json artifact",
     )
     args = ap.parse_args()
 
@@ -462,10 +361,6 @@ def main() -> None:
     t0 = time.perf_counter()
     _warmup(cfg, engine)
     print(f"warmup (compiles): {time.perf_counter() - t0:.1f}s")
-
-    if args.obs_only:
-        obs_bench(cfg, engine, args.obs_out)
-        return
 
     reqs = build_requests(cfg, N_REQUESTS, PROMPT_LEN, GEN)
     total_new = sum(r.max_new_tokens for r in reqs)
@@ -633,8 +528,6 @@ def main() -> None:
         json.dump(rows, f, indent=1)
         f.write("\n")
     print("wrote", out)
-
-    obs_bench(cfg, engine, args.obs_out)
 
 
 if __name__ == "__main__":

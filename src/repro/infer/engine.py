@@ -36,7 +36,6 @@ is the escape hatch.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from typing import Optional
@@ -51,6 +50,7 @@ from repro.infer.prefix_cache import (
     model_identity,
     pad_rows,
 )
+from repro.infer.spans import span
 from repro.infer.speculative import (
     SpecConfig,
     freeze_inactive,
@@ -193,6 +193,13 @@ class Engine:
         the :func:`jax.profiler.TraceAnnotation` scopes emitted here label
         those captures.
 
+        ``counters`` counts the target-model prefill of the slot-serving
+        path as it is dispatched: ``prefill_tokens`` (real prompt rows) and
+        ``prefill_rows`` (rows computed, bucket padding included). Decode
+        work is counted by the scheduler (``Scheduler.decode_steps``).
+        :meth:`programs_built` counts the executables the jitted programs
+        hold.
+
         ``prefix_cache`` (a :class:`repro.infer.prefix_cache.PrefixCache`)
         turns on prompt-prefix KV reuse for the slot-batched admission path
         (DESIGN.md §12): ``admit_slot`` consults it, installs matched prefix
@@ -202,6 +209,7 @@ class Engine:
         slot-batched serving)."""
         self.cfg = cfg
         self.tracer = tracer
+        self.counters = {"prefill_tokens": 0, "prefill_rows": 0}
         self.params = fuse_decode_projections(cfg, params) if fuse else params
         self.max_seq = max_seq
         self.embed_fn = embed_fn
@@ -625,20 +633,23 @@ class Engine:
         self.chunk_buckets = tuple(dict.fromkeys(buckets))
         self._last_prefix_handle: Optional[PrefixHandle] = None
 
-    def _obs_scope(self, name: str, **args):
-        """Host-side observability scope around one engine dispatch: a tracer
-        span on the ``engine`` lane (when a tracer is attached and enabled)
-        plus a ``jax.profiler.TraceAnnotation`` so the region is labelled in
-        ``jax.profiler.trace`` captures. Entered strictly outside jitted
-        code; a TraceAnnotation with no active profiler session is a cheap
-        no-op, and a disabled/absent tracer never reads a clock."""
-        ctx = contextlib.ExitStack()
-        if self.tracer is not None and self.tracer.enabled:
-            ctx.enter_context(
-                self.tracer.span(name, cat="engine", lane="engine", **args)
-            )
-        ctx.enter_context(jax.profiler.TraceAnnotation(name))
-        return ctx
+    def _count_prefill(self, tokens: int, rows: int) -> None:
+        self.counters["prefill_tokens"] += tokens
+        self.counters["prefill_rows"] += rows
+
+    def programs_built(self) -> int:
+        """Executables held by this engine's own jitted programs, summed
+        over their compile caches: a program compiled or loaded from the
+        persistent compilation cache counts alike. Read on demand, so it
+        costs the serving path nothing; it rises when a new shape (a new
+        whole-shot prompt length, a new chunk bucket) first runs. It does
+        not see the eager ops the engine runs outside those programs (the
+        per-length prompt slice and pad of chunked admission), which
+        compile too: a ``jax.monitoring`` compile listener sees those."""
+        return sum(
+            f._cache_size() for f in vars(self).values()
+            if hasattr(f, "_cache_size")
+        )
 
     def _make_cache(self, batch: int):
         """A fresh decode cache, TP-sharded (kv-heads over `model`) when the
@@ -846,9 +857,9 @@ class Engine:
                         self._bucket_for(0, handle.length)
                         if self._chunkable else handle.length
                     )
-                    with self._obs_scope(
-                        "engine/prefix_install", hit_tokens=handle.length,
-                        padded=total,
+                    with span(
+                        self.tracer, "engine/prefix_install", lane="engine",
+                        hit_tokens=handle.length, padded=total,
                     ):
                         cache1 = self._install_rows(
                             self._unit_cache, pad_rows(rows, total)
@@ -879,7 +890,10 @@ class Engine:
         :meth:`advance_admission`)."""
         if not p.target_done:
             if p.whole:
-                with self._obs_scope("engine/prefill", prompt_len=p.plen):
+                with span(
+                    self.tracer, "engine/prefill", lane="engine",
+                    prompt_len=p.plen,
+                ):
                     if p.collect:
                         p.logits1, vc = self._prefill_collect(
                             self.params, p.prompt, self._unit_cache
@@ -895,6 +909,7 @@ class Engine:
                         p.logits1 = logits[:, -1]
                 p.pos = p.plen
                 p.prefill_chunks += 1
+                self._count_prefill(p.plen, p.plen)
                 return p.plen
             n = p.plen - p.pos
             if left is not None:
@@ -906,8 +921,9 @@ class Engine:
             if b > n:
                 chunk = jnp.pad(chunk, ((0, 0), (0, b - n)))
             cache = p.cache1 if p.cache1 is not None else self._unit_cache
-            with self._obs_scope(
-                "engine/prefill_chunk", pos=p.pos, n_tokens=n, padded=b
+            with span(
+                self.tracer, "engine/prefill_chunk", lane="engine",
+                pos=p.pos, n_tokens=n, padded=b,
             ):
                 if p.collect:
                     last, vc = self._suffix_collect(
@@ -925,12 +941,14 @@ class Engine:
                     )
             p.pos += n
             p.prefill_chunks += 1
+            self._count_prefill(n, b)
             if p.pos >= p.plen:
                 p.logits1 = last
             return n
         spec = self._slot_spec
-        with self._obs_scope(
-            "engine/prefill_draft", prompt_len=p.plen, q_draft=spec.q_draft
+        with span(
+            self.tracer, "engine/prefill_draft", lane="engine",
+            prompt_len=p.plen, q_draft=spec.q_draft,
         ):
             _, p.dcache1 = self._prefill(
                 self.draft_params(spec.q_draft), p.prompt, None,
@@ -997,13 +1015,15 @@ class Engine:
             jnp.bool_(greedy),
         )
         if self._slot_spec is None:
-            with self._obs_scope("engine/admit", slot=slot):
+            with span(self.tracer, "engine/admit", lane="engine", slot=slot):
                 out = self._admit(
                     slots, jnp.int32(slot), p.cache1, p.logits1,
                     jax.random.PRNGKey(p.seed), *args,
                 )
         else:
-            with self._obs_scope("engine/admit", slot=slot, spec=True):
+            with span(
+                self.tracer, "engine/admit", lane="engine", slot=slot, spec=True
+            ):
                 out = self._admit_spec(
                     slots, jnp.int32(slot), p.cache1, p.dcache1, p.logits1,
                     jax.random.PRNGKey(p.seed),
@@ -1078,7 +1098,9 @@ class Engine:
         Returns `(tokens (B, n_steps) int32, active (B, n_steps) bool,
         new_slots)`; `tokens[b, t]` is a real emission iff `active[b, t]`.
         """
-        with self._obs_scope("engine/scan_decode", n_steps=n_steps):
+        with span(
+            self.tracer, "engine/scan_decode", lane="engine", n_steps=n_steps
+        ):
             return self._scan_decode_slots(self.params, slots, n_steps=n_steps)
 
     def spec_decode_slots(self, slots: dict, n_chunks: int):
@@ -1091,8 +1113,9 @@ class Engine:
         spec = self._slot_spec
         if spec is None or "draft_cache" not in slots:
             raise ValueError("slots were not initialised with speculate=...")
-        with self._obs_scope(
-            "engine/spec_chunks", n_chunks=n_chunks, gamma=spec.gamma
+        with span(
+            self.tracer, "engine/spec_chunks", lane="engine",
+            n_chunks=n_chunks, gamma=spec.gamma,
         ):
             return self._scan_spec_slots(
                 self.params, self.draft_params(spec.q_draft), slots,
@@ -1180,7 +1203,9 @@ class Engine:
                     f"garbage embedding rows device-side"
                 )
         cache = self._make_cache(b)
-        with self._obs_scope("engine/prefill", prompt_len=s, batch=b):
+        with span(
+            self.tracer, "engine/prefill", lane="engine", prompt_len=s, batch=b
+        ):
             logits, cache = self._prefill(
                 self.params, jnp.asarray(prompt_tokens), image_emb, cache
             )
@@ -1205,15 +1230,16 @@ class Engine:
                 )
             draft = self.draft_params(speculate.q_draft)
             dcache = self._make_cache(b)
-            with self._obs_scope(
-                "engine/prefill_draft", prompt_len=s, batch=b,
-                q_draft=speculate.q_draft,
+            with span(
+                self.tracer, "engine/prefill_draft", lane="engine",
+                prompt_len=s, batch=b, q_draft=speculate.q_draft,
             ):
                 _, dcache = self._prefill(
                     draft, jnp.asarray(prompt_tokens), image_emb, dcache
                 )
-            with self._obs_scope(
-                "engine/spec_generate", n_steps=n_steps, gamma=speculate.gamma
+            with span(
+                self.tracer, "engine/spec_generate", lane="engine",
+                n_steps=n_steps, gamma=speculate.gamma,
             ):
                 toks, (acc, prop, chunks) = self._spec_generate(
                     self.params, draft, logits[:, -1], cache, dcache,
@@ -1239,7 +1265,10 @@ class Engine:
             )
 
         if scan and cfg.input_kind == "tokens":
-            with self._obs_scope("engine/scan_decode", n_steps=n_steps, batch=b):
+            with span(
+                self.tracer, "engine/scan_decode", lane="engine",
+                n_steps=n_steps, batch=b,
+            ):
                 toks, _ = self._scan_decode(
                     self.params,
                     logits[:, -1],

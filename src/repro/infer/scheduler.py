@@ -96,6 +96,7 @@ from repro.infer.lifecycle import (
     RequestState,
     latency_summary,
 )
+from repro.infer.spans import span
 from repro.infer.speculative import SpecConfig
 
 
@@ -242,13 +243,16 @@ class Scheduler:
       boundary with the request's newly visible (post-truncation) tokens.
     - ``on_event(record)``: fired at every terminal transition with the
       request's :class:`RequestLifecycle` (partial tokens attached).
-    - ``tracer``: an optional :class:`repro.obs.trace.Tracer`. Lifecycle
+    - ``tracer``: an optional :class:`repro.obs.trace.Tracer`; without
+      one the scheduler records on its engine's ``tracer``, if any, so the
+      step's spans and the engine's dispatches share one ring. Lifecycle
       spans (queued/prefill/decode per request) are replayed from the
       record's *stored* timestamps at the terminal transition — tracing
       consumes zero extra scheduler-clock readings, so StepClock-driven
-      deadline behaviour is untouched. Per-chunk spans read the tracer's
-      own clock (``time.monotonic`` unless injected), a separate timebase
-      by design (DESIGN.md §11).
+      deadline behaviour is untouched. The step's phase spans
+      (``sched/*``, see :meth:`step`) read the tracer's own clock
+      (``time.monotonic`` unless injected), a separate timebase by design
+      (DESIGN.md §11).
     - ``metrics``: an optional :class:`repro.obs.metrics.MetricsRegistry`.
       Every ``counters`` increment goes through one helper that also bumps
       the registry's ``serve_<key>_total`` series, so the exported metrics
@@ -342,8 +346,11 @@ class Scheduler:
             "stopped_early": 0,
         }
         self._chunk_ordinal = 0  # decode dispatches over the lifetime
+        self._step_ordinal = 0  # step() calls over the lifetime
         self._rid_counter = itertools.count()
         self._used_rids = set()  # rids ever seen by THIS scheduler
+        if tracer is None:
+            tracer = getattr(engine, "tracer", None)
         self.tracer = tracer
         self.metrics = metrics
         pc = getattr(engine, "prefix_cache", None)
@@ -999,81 +1006,85 @@ class Scheduler:
 
     def step(self) -> List[Completion]:
         """One chunk boundary: apply cancels, enforce deadlines, admit into
-        free slots, run one decode chunk, harvest completions, guard NaNs."""
+        free slots, run one decode chunk, harvest completions, guard NaNs.
+
+        The step and each of its phases is a span (DESIGN.md §11.1):
+        ``sched/step`` around ``sched/admit``, ``sched/decode_chunk``,
+        ``sched/fetch``, ``sched/commit`` and ``sched/nan_guard``, in the
+        ring tracer (on the tracer's own clock, never the scheduler's:
+        tracing must not perturb StepClock-driven deadlines) and on the
+        device trace's clock."""
+        ordinal = self._step_ordinal
+        self._step_ordinal += 1
+        with span(
+            self.tracer, "sched/step", lane="scheduler", ordinal=ordinal,
+            queued=len(self.queue), prefilling=len(self._pending),
+            decoding=self.n_active,
+        ):
+            return self._step()
+
+    def _step(self) -> List[Completion]:
         done: List[Completion] = []
-        self._apply_cancels()
-        self._enforce_deadlines()
-        done.extend(self._admit_free_slots())
+        with span(self.tracer, "sched/admit", lane="scheduler"):
+            self._apply_cancels()
+            self._enforce_deadlines()
+            done.extend(self._admit_free_slots())
         if self.n_active == 0:
             self._observe_gauges()
             return done
-        # the chunk span reads the *tracer's* clock (never the scheduler's:
-        # tracing must not perturb StepClock-driven deadlines); a no-op
-        # handle when tracer is None/disabled
-        # the span covers dispatch AND the chunk-boundary host fetch — the
-        # fetch is the sync point, so this is the chunk's true wall time; it
-        # reads the *tracer's* clock (never the scheduler's: tracing must not
-        # perturb StepClock-driven deadlines)
-        span = (
-            self.tracer.span(
-                "decode_chunk", cat="scheduler", lane="scheduler",
-                ordinal=self._chunk_ordinal, active=self.n_active,
-                chunk=self.chunk, spec=self.speculate is not None,
-            )
-            if self.tracer is not None
-            else None
-        )
-        if span is not None:
-            span.__enter__()
-        try:
+        with span(
+            self.tracer, "sched/decode_chunk", lane="scheduler",
+            ordinal=self._chunk_ordinal, active=self.n_active,
+            chunk=self.chunk, spec=self.speculate is not None,
+        ):
             res = self._dispatch_decode()
-            if self.metrics is not None:
-                self.metrics.counter("serve_decode_chunks_total").inc()
-            if res is None:
-                self._observe_gauges()
-                return done
-            toks, valid, self.slots = res
-            self.decode_steps += self.chunk
-            if self.speculate is not None:
-                self.chunk_rows += self.n_active * self.chunk
+        if self.metrics is not None:
+            self.metrics.counter("serve_decode_chunks_total").inc()
+        if res is None:
+            self._observe_gauges()
+            return done
+        toks, valid, self.slots = res
+        self.decode_steps += self.chunk
+        if self.speculate is not None:
+            self.chunk_rows += self.n_active * self.chunk
+        # the chunk-boundary sync: the host waits here for the chunk to run
+        with span(self.tracer, "sched/fetch", lane="scheduler") as sp:
             toks = np.asarray(toks)  # (B, chunk) / (B, chunk*(gamma+1))  # staticcheck: host-sync(the one documented per-chunk fetch)
             valid = np.asarray(valid)  # staticcheck: host-sync(the one documented per-chunk fetch)
             committed = int(valid.sum())  # staticcheck: host-sync(valid already fetched above)
-            self.steps_active += committed
-            if span is not None:
-                span.annotate(tokens=committed)
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
-        if self.speculate is not None and self.tracer is not None:
-            self._trace_spec_subchunks(valid)
-        if self.metrics is not None:
-            self.metrics.histogram(
-                "serve_chunk_commit_tokens",
-                "tokens committed per decode chunk",
-                buckets=tuple(float(2**i) for i in range(10)),
-            ).observe(committed)
-
-        for slot, tenant in enumerate(self._tenants):
-            if tenant is None:
-                continue
-            stopped = self._record_tokens(
-                tenant, [int(t) for t in toks[slot][valid[slot]]]
-            )
-            if stopped:
-                done.append(self._finish(slot, stopped=True))
-            else:
-                c = self._harvest(slot)
-                if c is not None:
-                    done.append(c)
-        self._inject_and_guard_nan()
+            sp.annotate(tokens=committed)
+        self.steps_active += committed
+        with span(self.tracer, "sched/commit", lane="scheduler"):
+            if self.speculate is not None and self.tracer is not None:
+                self._trace_spec_subchunks(valid)
+            if self.metrics is not None:
+                self.metrics.histogram(
+                    "serve_chunk_commit_tokens",
+                    "tokens committed per decode chunk",
+                    buckets=tuple(float(2**i) for i in range(10)),
+                ).observe(committed)
+            for slot, tenant in enumerate(self._tenants):
+                if tenant is None:
+                    continue
+                stopped = self._record_tokens(
+                    tenant, [int(t) for t in toks[slot][valid[slot]]]
+                )
+                if stopped:
+                    done.append(self._finish(slot, stopped=True))
+                else:
+                    c = self._harvest(slot)
+                    if c is not None:
+                        done.append(c)
+        with span(self.tracer, "sched/nan_guard", lane="scheduler"):
+            self._inject_and_guard_nan()
         self._observe_gauges()
         return done
 
     def _trace_spec_subchunks(self, valid: np.ndarray) -> None:
         """Speculative draft/verify/rollback annotation. Draft + verify are
-        fused into the one device dispatch the chunk span already covers, so
-        per-sub-chunk *timing* needs ``jax.profiler`` (DESIGN.md §11); what
+        fused into the one device dispatch that ``sched/decode_chunk``
+        issues and ``sched/fetch`` waits on, so per-sub-chunk *timing* needs
+        ``jax.profiler`` (DESIGN.md §11); what
         the host does know exactly — per row and sub-chunk, how many drafted
         tokens the verifier kept and how many rolled back — is emitted as
         ``spec_verify`` instants derived from the fetched valid mask."""

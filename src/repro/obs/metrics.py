@@ -6,7 +6,7 @@ one sink for serving metrics — the scheduler, engine, async server and
 ``kernels/ops.py::qmatmul`` all write here — and it exports two ways:
 
 - :meth:`MetricsRegistry.snapshot` — a plain JSON-able dict (the ``/v1/
-  metrics`` JSON body and the ``benchmarks/serve_bench.py`` artifact);
+  metrics`` JSON body);
 - :func:`prometheus_text` — Prometheus text exposition format
   (``/v1/metrics?format=prometheus``), with :func:`parse_prometheus` as the
   matching mini-parser so the CI smoke job and tests validate the exact

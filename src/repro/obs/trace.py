@@ -87,14 +87,14 @@ class _NullSpan:
         pass
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class Tracer:
     """Thread-safe ring buffer of spans/instants with Chrome-trace export.
 
     >>> tr = Tracer(capacity=4096)
-    >>> with tr.span("decode_chunk", lane="scheduler", ordinal=3):
+    >>> with tr.span("sched/decode_chunk", lane="scheduler", ordinal=3):
     ...     ...
     >>> tr.complete("queued", t_submit, t_admit, lane="req:0")
     >>> json.dump(tr.to_chrome(), open("trace.json", "w"))
@@ -131,7 +131,7 @@ class Tracer:
         Disabled → a shared no-op handle (no clock reads, no allocation
         beyond the kwargs dict the caller already built)."""
         if not self.enabled:
-            return _NULL_SPAN
+            return NULL_SPAN
         return _SpanHandle(self, name, cat, lane, args)
 
     def complete(self, name: str, start: float, end: float, *, cat: str = "",

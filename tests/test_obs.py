@@ -16,6 +16,7 @@ The contract under test is three-sided:
 """
 
 import functools
+import glob
 import json
 import math
 import os
@@ -153,7 +154,7 @@ def test_disabled_tracer_never_reads_its_clock():
 def test_chrome_export_schema_valid_and_lanes_labelled():
     clk = StepClock(dt=0.5)
     tr = Tracer(clock=clk)
-    with tr.span("decode_chunk", cat="scheduler", lane="scheduler", ordinal=0):
+    with tr.span("sched/decode_chunk", cat="scheduler", lane="scheduler", ordinal=0):
         pass
     tr.complete("queued", 0.25, 0.75, cat="lifecycle", lane="req:0")
     tr.instant("finished", lane="req:0", args={"rid": 0})
@@ -383,7 +384,7 @@ def test_disturbed_serve_trace_reconstructs_and_metrics_account():
     assert {"finished", "failed", "cancelled", "shed"} <= by_state
     event_names = [e[1] for e in tracer.events()]
     assert "nan_quarantine" in event_names
-    assert "decode_chunk" in event_names
+    assert "sched/decode_chunk" in event_names
 
     # zero-leak accounting, through the exact bytes a scraper would see
     samples = parse_prometheus(prometheus_text(registry))
@@ -409,6 +410,7 @@ def test_instrumented_serving_token_identical():
     for r in _requests():
         plain_sched.submit(r)
     plain = {c.rid: c.new_tokens for c in plain_sched.run()}
+    built = eng.programs_built()
 
     tr, reg = Tracer(), MetricsRegistry()
     sched = Scheduler(eng, n_slots=2, chunk=3, tracer=tr, metrics=reg)
@@ -420,6 +422,8 @@ def test_instrumented_serving_token_identical():
     for rid in plain:
         np.testing.assert_array_equal(plain[rid], instrumented[rid])
     assert tr.stats()["recorded"] > 0  # it did actually trace
+    # the scheduler's step spans add no compile-cache entries
+    assert eng.programs_built() == built
     assert counters_agree(reg, sched.counters) == []
     total = sum(len(t) for t in plain.values())
     assert reg.counter("serve_tokens_total").value == total
@@ -510,6 +514,121 @@ def test_instrumentation_adds_no_compile_cache_entries():
     assert {"engine/prefill", "engine/scan_decode"} <= names
     solo = _engine().generate(p[None], 6)
     np.testing.assert_array_equal(out.tokens, solo.tokens)
+
+
+# ---------------------------------------------------------------------------
+# one span path, two sinks; the engine's work counters
+# ---------------------------------------------------------------------------
+
+SCHED_PHASES = ("sched/step", "sched/admit", "sched/decode_chunk",
+                "sched/fetch", "sched/commit", "sched/nan_guard")
+
+
+def _host_spans(profile_dir) -> dict:
+    """``name -> [(thread, start_ns, end_ns)]`` of the host events of the
+    ``jax.profiler`` capture under ``profile_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    ((plane.name, i), int(e.start_ns),
+                     int(e.start_ns + e.duration_ns))
+                )
+    return out
+
+
+def test_every_ring_span_lands_in_the_device_profile(tmp_path):
+    """One span, two sinks: a chunked serve under ``jax.profiler.trace``
+    puts each ``sched/*`` and ``engine/*`` span of the ring tracer into the
+    capture's host events under the same name, once per ring span, with
+    the fetch and the decode chunk nested in their step."""
+    tr = Tracer()
+    eng = Engine(_cfg(), init_params(KEY, _cfg()), max_seq=MAX_SEQ, tracer=tr)
+    sched = Scheduler(eng, n_slots=2, chunk=3, prefill_chunk=4, tracer=tr)
+    for r in _requests():
+        sched.submit(r)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        sched.run()
+
+    ring = [e[1] for e in tr.events()
+            if e[0] == "X" and e[1].startswith(("sched/", "engine/"))]
+    assert set(SCHED_PHASES) | {"engine/prefill_chunk", "engine/admit",
+                                "engine/scan_decode"} <= set(ring)
+    host = _host_spans(str(tmp_path))
+    for name in set(ring):
+        assert len(host.get(name, [])) == ring.count(name), name
+    steps = host["sched/step"]
+    for name in ("sched/fetch", "sched/decode_chunk"):
+        for thread, start, end in host[name]:
+            assert any(t == thread and s <= start and end <= e
+                       for t, s, e in steps), name
+
+
+def test_scheduler_records_on_its_engines_tracer():
+    """A scheduler given no tracer records on its engine's, so one ring
+    holds the step's phases and the dispatches nested in them."""
+    tr = Tracer()
+    eng = Engine(_cfg(), init_params(KEY, _cfg()), max_seq=MAX_SEQ, tracer=tr)
+    sched = Scheduler(eng, n_slots=2, chunk=3)
+    assert sched.tracer is tr
+    for r in _requests():
+        sched.submit(r)
+    sched.run()
+    names = {e[1] for e in tr.events()}
+    assert set(SCHED_PHASES) | {"engine/admit", "engine/scan_decode"} <= names
+    assert Scheduler(_engine(), n_slots=1, chunk=1).tracer is None
+
+
+@pytest.mark.parametrize("prefill_chunk", [None, 4])
+def test_engine_counters_count_what_is_dispatched(prefill_chunk):
+    """Whole-shot admission computes exactly the prompt rows; chunked
+    admission computes each chunk's bucket (8 rows, the smallest, for
+    chunks of at most 4 tokens here), so padding shows as rows over
+    tokens."""
+    eng = _engine()
+    before = dict(eng.counters)
+    sched = Scheduler(eng, n_slots=2, chunk=3, prefill_chunk=prefill_chunk)
+    reqs = _requests()
+    for r in reqs:
+        sched.submit(r)
+    sched.run()
+    got = {k: eng.counters[k] - before[k] for k in before}
+
+    plens = [int(r.prompt.size) for r in reqs]
+    if prefill_chunk is None:
+        rows = sum(plens)
+    else:
+        rows = 8 * sum(-(-p // prefill_chunk) for p in plens)
+    assert got == {"prefill_tokens": sum(plens), "prefill_rows": rows}
+
+
+def test_programs_built_rises_on_a_new_shape_only():
+    """Whole-shot admission compiles its prefill per prompt length: a
+    repeated length builds nothing, a new one builds its programs."""
+    eng = Engine(_cfg(), init_params(KEY, _cfg()), max_seq=MAX_SEQ)
+    corpus = MarkovCorpus(_cfg().vocab, seed=3)
+    slots = eng.init_slots(2)
+
+    def admit(plen, seed):
+        prompt = corpus.sample(1, plen, seed=seed)[0, :plen].astype(np.int32)
+        return eng.admit_slot(slots, 0, prompt, max_new_tokens=2)
+
+    slots = admit(5, 1)
+    built = eng.programs_built()
+    assert built > 0
+    slots = admit(5, 2)
+    assert eng.programs_built() == built
+    slots = admit(7, 3)
+    assert eng.programs_built() > built
 
 
 def test_obs_package_imports_without_jax():
