@@ -22,16 +22,12 @@ through the carry, and the host syncs once for the whole sequence instead of
 once per token. Embedding-input (modality-stub) models fall back to the
 per-token step loop because ``embed_fn`` runs host-side.
 
-Caveat (TPU): the scan threads the KV cache through the carry — the body
-reads the whole cache and dynamic-update-slices one slot per step. This is
-the standard JAX decode idiom (XLA's while-loop lowering updates loop-carried
-buffers in place), but it is a *different* access pattern from the
-layer-stacked cache-as-carry variant that ``models/layers.py::_cache_write``
-measured and rejected (dynamic per-layer slice reads triggered copy-insertion
-duplication of the carry). CPU-host timings (BENCH_decode.json: 1.44x over
-the step loop) cannot rule that pathology out on TPU — profile HBM traffic
-there before relying on the scan path at large ``max_seq``; ``scan=False``
-is the escape hatch.
+The KV cache stays in place through the decode program: the decode scan
+carries it, the model's layer scan carries each stage's stacked K/V cache in
+turn, and each step writes one row per batch row and layer into it
+(``models/model.py::_apply_stage``, ``models/layers.py::_cache_write``). The
+slot-serving programs donate the slot state, and ``generate`` donates its
+prefilled cache, so the carried cache and the argument are one buffer.
 """
 
 from __future__ import annotations
@@ -505,8 +501,11 @@ class Engine:
         # the param tree needs no static_argnums here
         self._prefill = jax.jit(_prefill)  # staticcheck: jit-ok(pytree statics; no donation — unit-cache template is reused)
         self._decode = jax.jit(_decode)  # staticcheck: jit-ok(pytree statics; cache threaded functionally by scan callers)
+        # the prefilled cache is dead after the decode scan: donate it so
+        # the scan's carried cache updates it in place
         self._scan_decode = jax.jit(
-            _scan_decode, static_argnames=("n_steps", "greedy")
+            _scan_decode, static_argnames=("n_steps", "greedy"),
+            donate_argnums=(2,),
         )
         # donate the incoming slot state: both return a full replacement and
         # the scheduler drops the old dict, so the n_slots-wide KV cache can

@@ -328,12 +328,19 @@ def attention(
     positions: Array,
     *,
     cache: Optional[dict] = None,
+    layer: Optional[Array] = None,
     pos: Optional[Array] = None,
     window: int = 0,
     kv_override: Optional[Tuple[Array, Array]] = None,
     chunked: bool = False,
 ) -> Tuple[Array, Optional[dict]]:
     """GQA attention. Returns (out, new_cache).
+
+    ``cache`` is the stage's *stacked* cache for this block (leaves
+    ``(repeat, B, s_max, ...)``) and ``layer`` this block's index in it: the
+    block writes only its new rows at ``[layer, b, pos]`` and reads its slab
+    back from the updated stack (``_cache_write``), and ``new_cache`` is the
+    updated stack.
 
     Modes
     -----
@@ -388,7 +395,7 @@ def attention(
             out = _local_attention_chunked(q, k, v, window)
         else:
             out = _causal(q, k, v)
-        new_cache = _cache_write(cache, k, v, pos, window)
+        new_cache = _cache_write(cache, layer, k, v, pos, window)
     elif s > 1:
         # chunked decode (speculative verify): s fresh tokens at absolute
         # positions pos..pos+s-1 against a *filled* cache. All s rows are
@@ -397,23 +404,22 @@ def attention(
         # so the unwrapped case is compute-identical to s single-token steps.
         # Ring ring-buffers additionally re-expose the entries the chunk's own
         # writes clobbered (live positions p - s_max once wrapped) as appended
-        # snapshot keys with their original validity band.
-        s_max_c = cache["k"].shape[1]
+        # snapshot keys with their original validity band: the only mode that
+        # reads cache rows before writing them, a gather of s rows per batch
+        # row from the stack, made before the write.
+        s_max_c = cache["k"].shape[2]
         pvec = pos if jnp.ndim(pos) == 1 else jnp.full((b,), pos, jnp.int32)
         snap = None
         if window:
             idx = (pvec[:, None] + jnp.arange(s)) % s_max_c  # (B, s) written slots
-            snap_k = _gather_rows(cache["k"], idx)
-            snap_v = _gather_rows(cache["v"], idx)
+            snap_k = _gather_rows(cache["k"], layer, idx)
+            snap_v = _gather_rows(cache["v"], layer, idx)
             if "k_scale" in cache:
-                snap_k = _kv_dequantize(snap_k, _gather_rows(cache["k_scale"], idx), x.dtype)
-                snap_v = _kv_dequantize(snap_v, _gather_rows(cache["v_scale"], idx), x.dtype)
+                snap_k = _kv_dequantize(snap_k, _gather_rows(cache["k_scale"], layer, idx), x.dtype)
+                snap_v = _kv_dequantize(snap_v, _gather_rows(cache["v_scale"], layer, idx), x.dtype)
             snap = (snap_k.astype(x.dtype), snap_v.astype(x.dtype))
-        new_cache = _cache_write(cache, k, v, pos, window)
-        ck, cv = new_cache["k"], new_cache["v"]
-        if "k_scale" in new_cache:
-            ck = _kv_dequantize(ck, new_cache["k_scale"], x.dtype)
-            cv = _kv_dequantize(cv, new_cache["v_scale"], x.dtype)
+        new_cache = _cache_write(cache, layer, k, v, pos, window)
+        ck, cv = _layer_kv(new_cache, layer, x.dtype)
         q = constrain_decode_q(q)
         qpos = pvec[:, None] + jnp.arange(s)  # (B, s) per-token absolute pos
         slot = jnp.arange(s_max_c)
@@ -448,11 +454,8 @@ def attention(
         # independent request at its own position — infer/scheduler). The
         # per-row mask values are identical to the scalar case, so a slotted
         # decode reproduces solo decodes bit-for-bit per row.
-        new_cache = _cache_write(cache, k, v, pos, window)
-        ck, cv = new_cache["k"], new_cache["v"]
-        if "k_scale" in new_cache:
-            ck = _kv_dequantize(ck, new_cache["k_scale"], x.dtype)
-            cv = _kv_dequantize(cv, new_cache["v_scale"], x.dtype)
+        new_cache = _cache_write(cache, layer, k, v, pos, window)
+        ck, cv = _layer_kv(new_cache, layer, x.dtype)
         q = constrain_decode_q(q)
         s_max = ck.shape[1]
         slot = jnp.arange(s_max)
@@ -478,10 +481,25 @@ def attention(
     return out, new_cache
 
 
-def _gather_rows(buf: Array, idx: Array) -> Array:
-    """Per-row gather of cache rows: buf (B, s_max, ...), idx (B, n) → (B, n, ...)."""
-    ix = idx.reshape(idx.shape + (1,) * (buf.ndim - 2))
-    return jnp.take_along_axis(buf, ix, axis=1)
+def _gather_rows(stack: Array, layer: Array, idx: Array) -> Array:
+    """Per-row gather of one layer's cache rows: stack (R, B, s_max, ...),
+    idx (B, n) → (B, n, ...)."""
+    rows = jnp.arange(idx.shape[0])[:, None]
+    return stack[layer, rows, idx]
+
+
+def _layer_kv(stack: dict, layer: Array, dtype) -> Tuple[Array, Array]:
+    """Layer ``layer``'s K/V slabs ``(B, s_max, Hkv, Dh)`` of the stacked
+    cache, dequantized when the cache is int8."""
+    sl = {
+        n: jax.lax.dynamic_index_in_dim(a, layer, axis=0, keepdims=False)
+        for n, a in stack.items()
+    }
+    ck, cv = sl["k"], sl["v"]
+    if "k_scale" in sl:
+        ck = _kv_dequantize(ck, sl["k_scale"], dtype)
+        cv = _kv_dequantize(cv, sl["v_scale"], dtype)
+    return ck, cv
 
 
 def _kv_quantize(x: Array):
@@ -499,29 +517,63 @@ def _kv_dequantize(q: Array, scale: Array, dtype) -> Array:
     return (q.astype(jnp.float32) * (scale[..., None] / 127.0)).astype(dtype)
 
 
-def _cache_write(cache: dict, k: Array, v: Array, pos: Array, window: int) -> dict:
-    """Write s new K/V rows at absolute position `pos` (ring buffer if local).
+def _cache_write(
+    cache: dict, layer: Array, k: Array, v: Array, pos: Array, window: int
+) -> dict:
+    """Write s new K/V rows at absolute position `pos` (ring buffer if local)
+    into layer ``layer`` of the stage's stacked cache (leaves
+    ``(repeat, B, s_max, ...)``); returns the updated stack.
 
-    Scalar-start ``dynamic_update_slice`` wherever possible: gather-index
-    scatters lower to whole-cache select/convert chains (measured 24 GB/step of
-    cache round-trips on llama3.2-3b decode_32k — §Perf cell A). A cache-as-
-    scan-carry variant with 5-D DUS was tried and REJECTED: XLA's copy
-    insertion duplicates the whole carry whenever the loop body also READS a
-    slice of it (measured 105 GB/step vs 15 GB for the xs/ys form).
+    The stack is the layer scan's carry (``models/model.py::_apply_stage``),
+    so a decode step writes only its new rows and XLA updates the carried
+    buffer in place: no per-layer slab is sliced out before the write and
+    none is restacked after it. The form of the row write decides that:
 
-    ``pos`` may also be a (B,) array (slot-batched serving decode and
-    speculative verify chunks): each batch row writes its ``s`` fresh rows at
-    its own position via a per-row DUS / ring scatter under vmap. That lowers
-    to a batched scatter — costlier than the scalar-start form, accepted on
-    the serving path where rows are independent requests by design.
+    - scalar ``pos``: one scalar-start ``dynamic_update_slice`` at
+      ``(layer, 0, pos, 0, 0)``;
+    - per-row ``(B,)`` ``pos`` (slot-batched serving decode, speculative
+      verify): a ``fori_loop`` over batch rows, each a scalar-start
+      ``dynamic_update_slice`` at ``(layer, b, pos[b], 0, 0)``. Do not vmap
+      the write over the batch axis: XLA cannot do the batched update in
+      place, and the compiled decode-slots program then copies the whole
+      stack (8 whole-cache copies and 1.16x the cache as temp in
+      ``tests/test_decode_inplace.py``'s dense case, CPU compile). A
+      ``.at[layer, rows, pos].set`` scatter stayed in place there and in a
+      TPU v5e compile;
+    - ring-buffer writes of several rows that may wrap: a scatter (the
+      speculative-verify chunk on a local-attention block; not on the
+      serving hot path).
+
+    Before, each layer's slab was sliced out of the stack as the scan's
+    ``xs`` and the updated slab restacked as its ``ys``: three whole-cache
+    passes per decode step (slice, restack, and the outer decode scan's
+    copy back into its carry), 57% of the device time of Phi-4-mini decode
+    at 20 slots x 2048 on a TPU v5e. Carried, its decode step fell from
+    70.5 to 33.4 ms (PERF.md). An earlier carry variant was rejected because
+    its body read a slice of the carry and then updated it; here attention
+    reads the slab only *after* the write, which needs no defensive copy.
     """
-    ck, cv = cache["k"], cache["v"]
-    s_max = ck.shape[1]
+    s_max = cache["k"].shape[2]
     s = k.shape[1]
-    quantized = "k_scale" in cache
-    if quantized:
-        k, k_scale = _kv_quantize(k)
-        v, v_scale = _kv_quantize(v)
+    new = {"k": k, "v": v}
+    if "k_scale" in cache:
+        new["k"], new["k_scale"] = _kv_quantize(k)
+        new["v"], new["v_scale"] = _kv_quantize(v)
+    layer = jnp.asarray(layer, jnp.int32)
+    zero = jnp.int32(0)
+
+    def dus(buf, rows, row, start):
+        # rows (n, s, ...) → buf[layer, row:row+n, start:start+s]
+        idxs = (layer, row, start) + (zero,) * (buf.ndim - 3)
+        return jax.lax.dynamic_update_slice(buf, rows[None].astype(buf.dtype), idxs)
+
+    def scatter(idx):
+        # idx (B, s) ring slots; rows may wrap, so no single start fits
+        rows = jnp.arange(idx.shape[0])[:, None]
+        return {
+            n: cache[n].at[layer, rows, idx].set(new[n].astype(cache[n].dtype))
+            for n in cache
+        }
 
     if jnp.ndim(pos) == 1:
         # per-row writes (slot-batched serving / speculative chunks): each
@@ -533,72 +585,31 @@ def _cache_write(cache: dict, k: Array, v: Array, pos: Array, window: int) -> di
             )
         if window and s > 1:
             # per-row partial ring fill (speculative verify on a ring buffer)
-            idx = (pos[:, None] + jnp.arange(s)) % s_max  # (B, s)
-
-            def set_rows(buf, new, ix):
-                return buf.at[ix].set(new.astype(buf.dtype))
-
-            write_b = jax.vmap(set_rows, in_axes=(0, 0, 0))
-            out = {"k": write_b(ck, k, idx), "v": write_b(cv, v, idx)}
-            if quantized:
-                out["k_scale"] = write_b(cache["k_scale"], k_scale, idx)
-                out["v_scale"] = write_b(cache["v_scale"], v_scale, idx)
-            return out
+            return scatter((pos[:, None] + jnp.arange(s)) % s_max)
         start_b = (pos % s_max if window else pos).astype(jnp.int32)
 
-        def dus_row(buf, new, st):
-            idxs = (st,) + (jnp.int32(0),) * (buf.ndim - 1)
-            return jax.lax.dynamic_update_slice(buf, new.astype(buf.dtype), idxs)
+        def write_row(b, bufs):
+            return {
+                n: dus(bufs[n], jax.lax.dynamic_slice_in_dim(new[n], b, 1), b, start_b[b])
+                for n in bufs
+            }
 
-        write_b = jax.vmap(dus_row, in_axes=(0, 0, 0))
-        out = {"k": write_b(ck, k, start_b), "v": write_b(cv, v, start_b)}
-        if quantized:
-            out["k_scale"] = write_b(cache["k_scale"], k_scale, start_b)
-            out["v_scale"] = write_b(cache["v_scale"], v_scale, start_b)
-        return out
-
-    def dus(buf, new, start, rank4=True):
-        new = new.astype(buf.dtype)
-        st = start.astype(jnp.int32) if hasattr(start, "astype") else jnp.int32(start)
-        zero = jnp.int32(0)
-        idxs = (zero, st, zero, zero) if rank4 else (zero, st, zero)
-        return jax.lax.dynamic_update_slice(buf, new, idxs)
-
-    def write(start):
-        out = {"k": dus(ck, k, start), "v": dus(cv, v, start)}
-        if quantized:
-            out["k_scale"] = dus(cache["k_scale"], k_scale, start, rank4=False)
-            out["v_scale"] = dus(cache["v_scale"], v_scale, start, rank4=False)
-        return out
+        return jax.lax.fori_loop(0, k.shape[0], write_row, dict(cache))
 
     if s >= s_max:
         # keep only the last s_max tokens (local-attn prefill over a window)
-        keep = slice(s - s_max, None)
-        k, v = k[:, keep], v[:, keep]
-        if quantized:
-            k_scale, v_scale = k_scale[:, keep], v_scale[:, keep]
+        new = {n: a[:, s - s_max :] for n, a in new.items()}
         if window:
             # ring phase: slot = abs_pos % s_max → roll the linear order
             base = (pos + s - s_max) % s_max
-            k = jnp.roll(k, base, axis=1)
-            v = jnp.roll(v, base, axis=1)
-            if quantized:
-                k_scale = jnp.roll(k_scale, base, axis=1)
-                v_scale = jnp.roll(v_scale, base, axis=1)
-        return write(jnp.int32(0))
+            new = {n: jnp.roll(a, base, axis=1) for n, a in new.items()}
+        return {n: dus(cache[n], new[n], zero, zero) for n in cache}
     if window and s > 1:
         # partial ring fill that may wrap — not used by any assigned shape
         idx = (pos + jnp.arange(s)) % s_max
-        out = {
-            "k": ck.at[:, idx].set(k.astype(ck.dtype)),
-            "v": cv.at[:, idx].set(v.astype(cv.dtype)),
-        }
-        if quantized:
-            out["k_scale"] = cache["k_scale"].at[:, idx].set(k_scale)
-            out["v_scale"] = cache["v_scale"].at[:, idx].set(v_scale)
-        return out
-    start = (pos % s_max) if window else pos
-    return write(start)
+        return scatter(jnp.broadcast_to(idx, (k.shape[0], s)))
+    start = jnp.asarray((pos % s_max) if window else pos, jnp.int32)
+    return {n: dus(cache[n], new[n], zero, start) for n in cache}
 
 
 def _ring_positions(slot: Array, total: Array, s_max: int) -> Array:

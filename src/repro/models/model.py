@@ -28,6 +28,7 @@ from repro.models.config import ModelConfig
 Array = jax.Array
 
 ATTN_BLOCKS = ("attn", "attn_moe", "local_attn", "cross")
+KV_BLOCKS = ("attn", "attn_moe", "local_attn")  # write K/V rows into the stack
 
 
 # ---------------------------------------------------------------------------
@@ -159,8 +160,16 @@ def apply_block(
     image_emb: Optional[Array],
     chunked: bool = False,
     collect: bool = False,
+    layer: Optional[Array] = None,
 ) -> Tuple[Array, Optional[dict], Array]:
     """Returns (h, new_cache, aux_loss).
+
+    For the self-attention blocks (``KV_BLOCKS``) ``cache`` is the stage's
+    stacked cache for this block (leaves ``(repeat, B, s_max, ...)``) and
+    ``layer`` the block's index in it: the block writes its new K/V rows
+    into the stack and returns the updated stack. For every other block
+    ``cache`` is this layer's own state (recurrent state or projected image
+    memory) and ``new_cache`` its replacement.
 
     ``chunked``/``collect`` implement the speculative-verify contract
     (DESIGN.md §5): multi-token decode against a filled cache, with recurrent
@@ -195,7 +204,7 @@ def apply_block(
         else:
             r, new_cache = L.attention(
                 p["attn"], cfg, L.rmsnorm(p["ln1"], h), positions,
-                cache=cache, pos=pos, window=window, chunked=chunked,
+                cache=cache, layer=layer, pos=pos, window=window, chunked=chunked,
             )
         h = h + r
         x2 = L.rmsnorm(p["ln2"], h)
@@ -232,27 +241,63 @@ def _apply_stage(
     chunked: bool = False,
     collect: bool = False,
 ) -> Tuple[Array, Optional[dict], Array]:
+    """Scan the stage's layers.
+
+    The self-attention blocks' stacked K/V caches ride in the scan's carry
+    beside the hidden state, and the layer index comes in beside the layer's
+    params. Each such block writes only its new rows into the stack and
+    reads its slab back after the write (``L._cache_write``), so a decode
+    step moves its new K/V rows and nothing else, and the carried stack, the
+    outer decode scan's carry and a donated slot state stay one buffer.
+    Slicing each layer's slab out as the scan's ``xs`` and restacking it as
+    its ``ys`` cost three whole-cache passes per decode step on the chip
+    (PERF.md). The windowed chunked verify, the one mode that reads K/V rows
+    before writing them, gathers those rows from the stack ahead of the
+    write (``L.attention``).
+
+    Every other block's state is small (recurrent state, projected image
+    memory) and stays ``xs``/``ys``: in the carry, XLA fused the state's
+    read into consumers on both sides of the in-place write and copied the
+    stacked state twice per layer, and with ``collect`` a recurrent block
+    returns per-step stacks shaped unlike its cache, which only ``ys`` can
+    hold."""
+    kv_names = {f"b{bi}" for bi, bt in enumerate(pattern) if bt in KV_BLOCKS}
+    carried, sliced = {}, None
+    if stage_cache is not None:
+        carried = {n: c for n, c in stage_cache.items() if n in kv_names}
+        sliced = {n: c for n, c in stage_cache.items() if n not in kv_names}
+
     def body(carry, xs):
-        hh, aux = carry
-        layer_p, layer_c = xs
-        new_c = {}
+        hh, aux, kv = carry
+        layer_p, layer_c, layer = xs
+        kv, new_c = dict(kv), {}
         for bi, btype in enumerate(pattern):
-            c_in = None if layer_c is None else layer_c[f"b{bi}"]
+            name = f"b{bi}"
+            if name in kv:
+                c_in = kv[name]
+            else:
+                c_in = None if layer_c is None else layer_c[name]
             hh, c_out, a = apply_block(
-                layer_p[f"b{bi}"], cfg, btype, hh, positions, c_in, pos, image_emb,
-                chunked=chunked, collect=collect,
+                layer_p[name], cfg, btype, hh, positions, c_in, pos, image_emb,
+                chunked=chunked, collect=collect, layer=layer,
             )
             aux = aux + a
-            if layer_c is not None:
-                new_c[f"b{bi}"] = c_out if c_out is not None else c_in
-        return (hh, aux), (new_c if layer_c is not None else None)
+            if name in kv:
+                kv[name] = c_out
+            elif layer_c is not None:
+                new_c[name] = c_out if c_out is not None else c_in
+        return (hh, aux, kv), new_c
 
     if remat:
         body = jax.checkpoint(body)
 
-    (h, aux), new_cache = jax.lax.scan(
-        body, (h, jnp.float32(0.0)), (stage_params, stage_cache)
+    repeat = jax.tree.leaves(stage_params)[0].shape[0]
+    (h, aux, kv), new_sliced = jax.lax.scan(
+        body,
+        (h, jnp.float32(0.0), carried),
+        (stage_params, sliced, jnp.arange(repeat, dtype=jnp.int32)),
     )
+    new_cache = None if stage_cache is None else {**kv, **new_sliced}
     return h, new_cache, aux
 
 

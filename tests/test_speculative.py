@@ -282,6 +282,51 @@ def test_chunked_decode_matches_step_decode():
         )
 
 
+@pytest.mark.parametrize(
+    "case", ["local_attn", "recurrentgemma-9b"], ids=["ring", "ring+rglru"]
+)
+def test_chunked_verify_on_wrapped_ring_matches_step_decode(case):
+    """Windowed chunked verify on a ring buffer that has wrapped: the chunk's
+    own writes clobber rows its earlier tokens still attend, so the verify
+    gathers those rows from the stacked cache before writing. Its logits and
+    the cache it leaves must match s single-token decode steps."""
+    if case == "local_attn":
+        cfg = reduced(
+            get_config("llama3.2-3b"), window=8, stages=((("local_attn",), 2),)
+        )
+    else:
+        cfg = reduced(get_config(case))
+    params = init_params(KEY, cfg)
+    b, n_prompt, n_chunk = 2, 20, 6  # prompt > 2 windows: the ring has wrapped
+    toks = _prompts(cfg, b, n_prompt + n_chunk)
+    prompt, rest = toks[:, :n_prompt], toks[:, n_prompt:]
+    _, cache, _ = forward(
+        cfg, params, tokens=jnp.asarray(prompt), cache=init_cache(cfg, b, 40),
+        pos=jnp.int32(0), logits_mode="last",
+    )
+    step_cache, step_logits = cache, []
+    for t in range(n_chunk):
+        lg, step_cache, _ = forward(
+            cfg, params, tokens=jnp.asarray(rest[:, t : t + 1]),
+            cache=step_cache, pos=jnp.full((b,), n_prompt + t, jnp.int32),
+            logits_mode="last",
+        )
+        step_logits.append(np.asarray(lg[:, 0]))
+    chunk_logits, chunk_cache, _ = forward(
+        cfg, params, tokens=jnp.asarray(rest), cache=cache,
+        pos=jnp.full((b,), n_prompt, jnp.int32), logits_mode="all",
+        chunked_decode=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(chunk_logits), np.stack(step_logits, axis=1),
+        rtol=2e-5, atol=2e-5,
+    )
+    for got, want in zip(jax.tree.leaves(chunk_cache), jax.tree.leaves(step_cache)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+        )
+
+
 # ---------------------------------------------------------------------------
 # speculative continuous batching
 # ---------------------------------------------------------------------------
