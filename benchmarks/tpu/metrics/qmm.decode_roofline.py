@@ -20,6 +20,6 @@ def read(run):
     tokens = run.window.counters["steps_active"]
     if not t or not steps:
         return None
-    flops, bytes_ = work.qmm_work(run.mc, run.qc, passes=steps, rows=tokens,
-                                  head_rows=tokens)
+    flops, bytes_ = work.qmm_work(run.arch, run.mc, run.qc, passes=steps,
+                                  rows=tokens, head_rows=tokens)
     return 100.0 * work.roofline_s(flops, bytes_, run.peak) / t

@@ -20,6 +20,6 @@ def read(run):
         return None
     tokens = sum(n for _, n, _ in chunks)
     last = sum(1 for pos, n, plen in chunks if pos + n >= plen)
-    flops, bytes_ = work.qmm_work(run.mc, run.qc, passes=len(chunks), rows=tokens,
-                                  head_rows=last)
+    flops, bytes_ = work.qmm_work(run.arch, run.mc, run.qc, passes=len(chunks),
+                                  rows=tokens, head_rows=last)
     return 100.0 * work.roofline_s(flops, bytes_, run.peak) / t

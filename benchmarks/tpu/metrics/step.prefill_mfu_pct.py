@@ -17,8 +17,8 @@ def read(run):
         return None
     tokens = sum(n for _, n, _ in chunks)
     last = sum(1 for pos, n, plen in chunks if pos + n >= plen)
-    flops = 2.0 * work.linear_params(run.mc, head=False) * tokens
-    flops += 2.0 * last * run.mc["d_model"] * run.mc["vocab"]
-    flops += work.attention_flops(run.mc, (p + 1 for pos, n, _ in chunks
-                                           for p in range(pos, pos + n)))
+    flops = 2.0 * work.linear_params(run.arch, run.mc, head=False) * tokens
+    flops += work.head_flops(run.arch, run.mc, last)
+    flops += work.attention_flops(run.arch, run.mc, (p + 1 for pos, n, _ in chunks
+                                                     for p in range(pos, pos + n)))
     return 100.0 * flops / (t * run.peak["bf16_flops"])

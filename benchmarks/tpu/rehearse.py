@@ -26,7 +26,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from tpubench import spec, weights  # noqa: E402
+from tpubench import arch, spec, weights  # noqa: E402
 
 GiB = 2**30
 
@@ -56,7 +56,8 @@ def main() -> None:
         return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), tree)
 
-    plain = jax.eval_shape(weights.weights_fn(cell.config), jax.random.PRNGKey(0))
+    plain = jax.eval_shape(arch.of(cell.config).weights_fn(cell.config),
+                           jax.random.PRNGKey(0))
     cfg, params = weights.program_params(placed(plain), cell.config)
     engine = Engine(cfg, params, max_seq=server["max_seq"], fuse=False)
     slots = placed(jax.eval_shape(lambda: engine.init_slots(server["n_slots"])))

@@ -9,12 +9,13 @@ import math
 import os
 import shutil
 import time
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
-from tpubench import reference, serve, spec, traffic, weights
+from tpubench import arch, reference, serve, spec, traffic, weights
 from tpubench import trace as tr
 from tpubench.peaks import peaks
 
@@ -29,6 +30,7 @@ _FAILED = ("failed", "cancelled", "timed_out", "shed")
 class Run:
     """What a per-layer metric reader gets."""
 
+    arch: ModuleType  # the configuration's architecture module (tpubench.arch)
     mc: dict  # model_config of the configuration file
     qc: dict  # quant of the configuration file
     server: dict
@@ -235,8 +237,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
             metrics[m.name] = {"value": e2e[m.name], "unit": m.unit}
     else:
         trc = tr.load(trace_dir)
-        run = Run(mc=config["model_config"], qc=config["quant"], server=server,
-                  window=w, trace=trc, peak=peaks(device.device_kind))
+        run = Run(arch=arch.of(config), mc=config["model_config"], qc=config["quant"],
+                  server=server, window=w, trace=trc, peak=peaks(device.device_kind))
         for m in cell.per_layer:
             v = spec.load_reader(m.name)(run)
             if v is None:

@@ -1,13 +1,12 @@
-"""A plain float32 reference of the dense decoder the configurations describe,
-and the comparison that decides ``correct``.
+"""A plain float32 reference of the model a configuration describes, and the
+comparison that decides ``correct``.
 
-The block is the published one: pre-norm RMSNorm, grouped-query attention
-with rotary embeddings (rotate-half layout, ``inv_freq = theta^(-2i/d)``) and
-a causal mask, and a SwiGLU MLP; then a final RMSNorm and the output head.
-Linear weights are BCQ codes: ``W[k, o] = sum_i scales[i, k // g, o] *
-(2 * bit_i[k, o] - 1)``, with bit ``j`` of byte ``c`` of plane ``i`` holding
-row ``8 c + j``. The reference unpacks them itself, from the arrays the
-benchmark made from the seed, and imports nothing of the program.
+The forward itself is the configuration's architecture module's
+(``tpubench.arch``); it builds on the pieces here. Linear weights are BCQ
+codes: ``W[k, o] = sum_i scales[i, k // g, o] * (2 * bit_i[k, o] - 1)``, with
+bit ``j`` of byte ``c`` of plane ``i`` holding row ``8 c + j``. The reference
+unpacks them itself, from the arrays the benchmark made from the seed, and
+imports nothing of the program.
 
 It runs layer by layer over a block of sequences (prompt plus served tokens,
 teacher-forced), with every matmul at ``precision=highest``, and reads the
@@ -25,6 +24,8 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from tpubench import arch
 
 F8_MAX = 448.0  # largest finite float8_e4m3fn
 
@@ -56,86 +57,6 @@ def _mm(a: jax.Array, w: jax.Array, control: bool) -> jax.Array:
     return jnp.einsum("...k,ko->...o", a, w, precision=jax.lax.Precision.HIGHEST)
 
 
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w.astype(jnp.float32)
-
-
-def _rope(x, theta):
-    """``x (R, S, H, d)`` at positions ``0..S-1``, rotate-half layout."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv  # (S, d/2)
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _attention(q, k, v, control: bool, block: int):
-    """Causal GQA. ``q (R, S, H, d)``, ``k, v (R, S, Hkv, d)``; queries in
-    blocks of ``block`` rows so the scores stay small."""
-    r, s, h, d = q.shape
-    hkv = k.shape[2]
-    qg = q.reshape(r, s, hkv, h // hkv, d)
-    block = min(block, s)
-    if control:
-        qg, k, v = _f8(qg, -1), _f8(k, -1), _f8(v, -1)
-    nb = s // block
-
-    def one(i):
-        qb = jax.lax.dynamic_slice_in_dim(qg, i * block, block, axis=1)
-        sc = jnp.einsum("rqhgd,rkhd->rhgqk", qb, k,
-                        precision=jax.lax.Precision.HIGHEST) / np.sqrt(d)
-        qpos = i * block + jnp.arange(block)
-        sc = jnp.where(jnp.arange(s)[None, :] <= qpos[:, None], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        if control:
-            p = _f8(p, -1)
-        return jnp.einsum("rhgqk,rkhd->rqhgd", p, v, precision=jax.lax.Precision.HIGHEST)
-
-    out = jax.lax.map(one, jnp.arange(nb))  # (nb, r, block, hkv, g, d)
-    return jnp.moveaxis(out, 0, 1).reshape(r, s, h * d)
-
-
-def _forward(mc: dict, g: int, control: bool, block: int, w: dict, tokens, rows):
-    """Final-normed hidden states of ``tokens (R, S)`` at ``rows (N, 2)``
-    (sequence, position) and the logits there, ``(N, V)``."""
-    h_q = mc["n_heads"] * mc["d_head"]
-    h_kv = mc["n_kv_heads"] * mc["d_head"]
-    eps, theta, dh = mc["norm_eps"], mc["rope_theta"], mc["d_head"]
-    x = w["embed"][tokens].astype(jnp.float32)
-    r, s, _ = x.shape
-
-    def layer(x, lw):
-        h = _rms(x, lw["ln1"], eps)
-        qkv = _mm(h, unpack(lw["wqkv"]["packed"], lw["wqkv"]["scales"], g), control)
-        q = qkv[..., :h_q].reshape(r, s, mc["n_heads"], dh)
-        k = qkv[..., h_q:h_q + h_kv].reshape(r, s, mc["n_kv_heads"], dh)
-        v = qkv[..., h_q + h_kv:].reshape(r, s, mc["n_kv_heads"], dh)
-        a = _attention(_rope(q, theta), _rope(k, theta), v, control, block)
-        x = x + _mm(a, unpack(lw["wo"]["packed"], lw["wo"]["scales"], g), control)
-        h = _rms(x, lw["ln2"], eps)
-        gu = _mm(h, unpack(lw["w_gate_up"]["packed"], lw["w_gate_up"]["scales"], g), control)
-        f = gu.shape[-1] // 2
-        act = jax.nn.silu(gu[..., :f]) * gu[..., f:]
-        x = x + _mm(act, unpack(lw["w_down"]["packed"], lw["w_down"]["scales"], g), control)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, w["layers"])
-    hsel = _rms(x[rows[:, 0], rows[:, 1]], w["final_norm"], eps)
-    packed, scales = w["lm_head"]["packed"], w["lm_head"]["scales"]
-    vocab = packed.shape[-1]
-    nb = next(n for n in (8, 4, 2, 1) if vocab % n == 0)  # head in vocab blocks
-    vb = vocab // nb
-
-    def head(i):
-        p = jax.lax.dynamic_slice_in_dim(packed, i * vb, vb, axis=-1)
-        s = jax.lax.dynamic_slice_in_dim(scales, i * vb, vb, axis=-1)
-        return _mm(hsel, unpack(p, s, g), control)
-
-    out = jax.lax.map(head, jnp.arange(nb))  # (nb, N, vb)
-    return jnp.moveaxis(out, 0, 1).reshape(hsel.shape[0], vocab)
-
-
 _JIT: Dict[tuple, object] = {}
 
 
@@ -145,7 +66,8 @@ def logits(config: dict, weights: dict, tokens: np.ndarray, rows: np.ndarray,
     mc, g = config["model_config"], config["quant"]["g"]
     key = (config["name"], control, block)
     if key not in _JIT:
-        _JIT[key] = jax.jit(lambda w, t, r: _forward(mc, g, control, block, w, t, r))
+        fwd = arch.of(config).forward
+        _JIT[key] = jax.jit(lambda w, t, r: fwd(mc, g, control, block, w, t, r))
     return _JIT[key](weights, jnp.asarray(tokens), jnp.asarray(rows))
 
 

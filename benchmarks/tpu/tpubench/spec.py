@@ -1,7 +1,9 @@
 """Find a cell's configuration, traffic and metrics by the names in
 ``BENCHMARK.json``. Nothing here knows a particular cell: a new cell is a new
 entry there plus data files under ``configs/`` and ``traffic/``, and a new
-per-layer metric is a new reader under ``metrics/``."""
+per-layer metric is a new reader under ``metrics/``. A new architecture adds
+a module under ``tpubench/archs/`` (``tpubench.arch`` says what it provides),
+a configuration file that names it in its ``"arch"``, and a traffic file."""
 
 from __future__ import annotations
 
