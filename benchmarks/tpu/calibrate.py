@@ -29,7 +29,7 @@ os.environ["REPRO_AUTOTUNE"] = "0"
 
 def calibrate(cell, seeds, seconds, log=print):
     from tpubench import cell as C
-    from tpubench import reference, serve
+    from tpubench import reference, serve, weights
 
     tf = cell.traffic
     engine = None
@@ -38,15 +38,17 @@ def calibrate(cell, seeds, seconds, log=print):
         plain, engine, arrivals = C.setup(cell, seed, seconds, engine)
         w = serve.serve(engine, arrivals, tf["server"], seconds,
                         drain_max_s=C.DRAIN_MAX_S)
+        engine.params = None  # free this seed's weights before the next
         sample = C.sample_finished(w, tf["check"], seed)
         seqs = [(C._prompt(arrivals, w, r), r.tokens) for r in sample]
+        if plain is None:  # a tensor-parallel engine held the only copy
+            plain = weights.make_weights(cell.config, seed, engine.mesh)
         got = reference.compare(cell.config, plain, seqs, C.check_shape(tf), control=True)
         got.update(seed=seed, seconds=time.perf_counter() - t,
                    failed=sum(1 for r in w.in_window() if r.first is None),
                    wrong_length=sum(1 for r in w.records if r.state == "finished"
                                     and len(r.tokens) != r.max_new))
         log(json.dumps(got))
-        engine.params = None  # free this seed's weights before the next
         del plain
 
 

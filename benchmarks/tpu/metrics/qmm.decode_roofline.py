@@ -21,5 +21,5 @@ def read(run):
     if not t or not steps:
         return None
     flops, bytes_ = work.qmm_work(run.arch, run.mc, run.qc, passes=steps,
-                                  rows=tokens, head_rows=tokens)
+                                  rows=tokens, head_rows=tokens, tp=run.tp)
     return 100.0 * work.roofline_s(flops, bytes_, run.peak) / t

@@ -21,5 +21,5 @@ def read(run):
     tokens = sum(n for _, n, _ in chunks)
     last = sum(1 for pos, n, plen in chunks if pos + n >= plen)
     flops, bytes_ = work.qmm_work(run.arch, run.mc, run.qc, passes=len(chunks),
-                                  rows=tokens, head_rows=last)
+                                  rows=tokens, head_rows=last, tp=run.tp)
     return 100.0 * work.roofline_s(flops, bytes_, run.peak) / t

@@ -15,6 +15,7 @@ def read(run):
     toks = run.window_tokens()
     if not t or not toks:
         return None
-    flops = 2.0 * work.linear_params(run.arch, run.mc) * len(toks)
-    flops += work.attention_flops(run.arch, run.mc, (plen + j + 1 for plen, j in toks))
+    flops = 2.0 * work.linear_params(run.arch, run.mc, tp=run.tp) * len(toks)
+    flops += work.attention_flops(run.arch, run.mc, (plen + j + 1 for plen, j in toks),
+                                   run.tp)
     return 100.0 * flops / (t * run.peak["bf16_flops"])

@@ -17,8 +17,9 @@ def read(run):
         return None
     tokens = sum(n for _, n, _ in chunks)
     last = sum(1 for pos, n, plen in chunks if pos + n >= plen)
-    flops = 2.0 * work.linear_params(run.arch, run.mc, head=False) * tokens
-    flops += work.head_flops(run.arch, run.mc, last)
+    flops = 2.0 * work.linear_params(run.arch, run.mc, head=False, tp=run.tp) * tokens
+    flops += work.head_flops(run.arch, run.mc, last, run.tp)
     flops += work.attention_flops(run.arch, run.mc, (p + 1 for pos, n, _ in chunks
-                                                     for p in range(pos, pos + n)))
+                                                     for p in range(pos, pos + n)),
+                                   run.tp)
     return 100.0 * flops / (t * run.peak["bf16_flops"])

@@ -73,7 +73,8 @@ def measure(cell, seed: int, seconds: float, profile: bool, *, log=print):
     from tpubench import trace as tr
 
     server = cell.traffic["server"]
-    # the plain weights stay on the device while serving, as in run.py
+    # the plain weights stay on the device while serving, as in run.py (a
+    # tensor-parallel engine holds the only copy, and set-up returns None)
     plain, engine, arrivals = C.setup(cell, seed, seconds, log=log)
     # serve's clock; the scheduler serve() builds records on its engine's
     ring = Tracer(capacity=1 << 17, clock=time.perf_counter)

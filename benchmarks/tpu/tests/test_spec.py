@@ -11,8 +11,16 @@ from tpubench import spec
 BENCH = json.load(open(os.path.join(spec.ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj|head_dim|_dim$|_rank$|"
+# keys that name a width, which ``reduced`` may never list; the depth
+# (``num_hidden_layers``) may be cut
+WIDTH = re.compile(r"(hidden(?!_layers)|intermediate|latent|state|proj|head_dim|_dim$|_rank$|"
                    r"expansion|experts_per_tok|num_experts_per)")
+
+
+def test_the_width_rule_tells_depth_from_width():
+    assert all(WIDTH.search(k) for k in ("hidden_size", "intermediate_size", "head_dim",
+                                         "kv_lora_rank", "num_experts_per_tok"))
+    assert not any(WIDTH.search(k) for k in ("num_hidden_layers", "rms_norm_eps"))
 
 
 def test_top_level_keys():

@@ -17,6 +17,14 @@ code that knows that architecture's layers, and provides:
 - ``forward(mc, g, control, block, w, tokens, rows) -> logits``: the float32
   reference, blockwise.
 
+A module whose configurations state tensor parallelism (``"parallel":
+{"tp": n}``) also gives ``SPLIT``: for each linear, how the program splits
+it over the chips, ``"col"`` (the output dim) or ``"row"`` (the reduction
+dim). The weights are made in that layout and the work is counted per chip
+by it. Such a module also gives ``RELAYOUT``, the leaves whose columns the
+program re-orders before it splits them (an empty tuple where it re-orders
+none): those reach the program from host memory.
+
 The rest of the harness (weights, reference, work, metric readers) calls
 through these and knows no layer.
 """
@@ -57,6 +65,24 @@ def load(name: str) -> ModuleType:
             raise ValueError(f"arch module {path} lacks {missing}")
         _LOADED[path] = mod
     return _LOADED[path]
+
+
+def split(mod: ModuleType, name: str) -> str:
+    """``"col"`` or ``"row"``: how tensor parallelism splits linear ``name``."""
+    try:
+        return mod.SPLIT[name]
+    except (AttributeError, KeyError):
+        raise ValueError(f"arch module {mod.__name__} gives no tensor-parallel split "
+                         f"for linear {name!r} (its SPLIT)") from None
+
+
+def relayout(mod: ModuleType) -> tuple:
+    """The leaves whose columns tensor parallelism re-orders (``RELAYOUT``)."""
+    try:
+        return tuple(mod.RELAYOUT)
+    except AttributeError:
+        raise ValueError(f"arch module {mod.__name__} gives SPLIT but no RELAYOUT: "
+                         f"the leaves whose columns the program re-orders") from None
 
 
 def of(config: dict) -> ModuleType:

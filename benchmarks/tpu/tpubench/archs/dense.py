@@ -45,6 +45,15 @@ def linears(mc: dict) -> List[Tuple[str, int, int, int]]:
     ]
 
 
+# How parallel/tp.py splits each linear over a tensor-parallel mesh:
+# column-parallel over output columns (heads, FFN columns, vocab), row-parallel
+# over the reduction dim (partial sums all-reduced).
+SPLIT = {"wqkv": "col", "w_gate_up": "col", HEAD: "col", "wo": "row", "w_down": "row"}
+# The fused leaves whose columns shard_model re-orders before it splits them
+# (parallel/tp.py::relayout_fused_for_tp).
+RELAYOUT = ("wqkv", "w_gate_up")
+
+
 def attention_layers(mc: dict) -> int:
     return mc["n_layers"]
 

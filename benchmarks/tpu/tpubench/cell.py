@@ -37,6 +37,7 @@ class Run:
     window: serve.Window
     trace: Optional[tr.Trace]
     peak: dict
+    tp: int = 1  # chips each layer is split over; the work is counted per chip
 
     def window_tokens(self) -> List[tuple]:
         """``(prompt_len, index)`` of each token handed over inside the
@@ -130,26 +131,53 @@ def sample_finished(w: serve.Window, check: dict, seed: int) -> List[serve.Recor
 
 def setup(cell: spec.Cell, seed: int, seconds: float, engine=None, log=print):
     """Weights from the seed, the engine over them (or ``engine`` given the
-    new weights), the traffic, and every program the window drives."""
+    new weights), the traffic, and every program the window drives.
+
+    A cell whose configuration states tensor parallelism serves through the
+    program's own mesh path, ``Engine(mesh=...)``. The weights are made split
+    over the chips. ``shard_model`` re-orders the fused leaves' columns with
+    a gather that, on a chip, needs several times that chip's share of a
+    stacked leaf, so those leaves reach it from host memory, as from a
+    checkpoint. The engine then holds the only copy on the chips, and
+    ``plain`` comes back None: the reference makes it again from the
+    seed."""
     from repro.infer import Engine
 
     config, tf = cell.config, cell.traffic
     server = tf["server"]
+    mesh = weights.mesh(cell.tp)
     t = time.perf_counter()
-    plain = weights.make_weights(config, seed)
+    plain = weights.make_weights(config, seed, mesh)
     jax.block_until_ready(plain)
     t_w = time.perf_counter()
+    if mesh is not None:
+        plain = weights.to_host(plain, arch.relayout(arch.of(config)))
+    t_h = time.perf_counter()
     cfg, params = weights.program_params(plain, config)
+    if mesh is not None:
+        plain = None
     if engine is None:
-        engine = Engine(cfg, params, max_seq=server["max_seq"], fuse=False)
-    else:
+        engine = Engine(cfg, params, max_seq=server["max_seq"], fuse=False, mesh=mesh)
+    elif mesh is None:
         engine.params = params
+    else:
+        from repro.parallel.tp import shard_model
+
+        engine.params, _ = shard_model(cfg, params, mesh)
+    del params
+    log(f"weights {t_w - t:.1f}s, to host {t_h - t_w:.1f}s, engine {time.perf_counter() - t_h:.1f}s "
+        f"(over {cell.tp} chip(s))")
     arrivals = traffic.schedule(tf, cfg.vocab, seed, horizon_s=seconds + DRAIN_MAX_S)
     t_e = time.perf_counter()
     warm(engine, server, arrivals, log=log)
-    log(f"set-up: weights {t_w - t:.1f}s, engine and traffic {t_e - t_w:.1f}s, "
-        f"warm-up {time.perf_counter() - t_e:.1f}s")
+    log(f"set-up: weights {t_w - t:.1f}s, to host {t_h - t_w:.1f}s, engine and traffic "
+        f"{t_e - t_h:.1f}s, warm-up {time.perf_counter() - t_e:.1f}s")
     return plain, engine, arrivals
+
+
+def memory(devices, key: str) -> int:
+    """The largest ``memory_stats()[key]`` over ``devices``."""
+    return max(int((d.memory_stats() or {}).get(key, 0)) for d in devices)
 
 
 def check_shape(tf: dict) -> tuple:
@@ -169,12 +197,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
     plain, engine, arrivals = setup(cell, seed, seconds, log=log)
     if engine_hook is not None:
         engine_hook(engine)
-    log(f"set-up done: weights {weights.nbytes(plain) / 2**30:.3f} GiB, "
-        f"{len(arrivals)} requests scheduled")
+    mesh = engine.mesh
+    devices = [device] if mesh is None else list(mesh.devices.flat)
+    log(f"set-up done: {len(arrivals)} requests scheduled")
 
     # -- the window --------------------------------------------------------------
     trace_dir = os.path.join(spec.ROOT, ".bench_trace") if trace_on else None
     spans = []
+    in_use = []
 
     def on_open():
         if trace_dir:
@@ -188,6 +218,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
             spans[0].__enter__()
 
     def on_close():
+        in_use.append(memory(devices, "bytes_in_use"))
         if trace_dir:
             spans[0].__exit__(None, None, None)
 
@@ -199,14 +230,15 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
                     on_close=on_close, on_settled=on_settled,
                     count_s=tf.get("trace_s", TRACE_S) if trace_on else None, drain_max_s=DRAIN_MAX_S)
     setup_s = w.loop_start - t_start
-    stats = device.memory_stats() or {}
-    mem_peak = int(stats.get("peak_bytes_in_use", 0))
+    mem_peak = memory(devices, "peak_bytes_in_use")
 
     # -- free the program's state, then check against the reference ----------------
     del engine
     gc.collect()
     sample = sample_finished(w, check, seed)
     t = time.perf_counter()
+    if plain is None and sample:
+        plain = weights.make_weights(config, seed, mesh)
     cmp = reference.compare(config, plain, [(_prompt(arrivals, w, r), r.tokens)
                                             for r in sample], check_shape(tf)) \
         if sample else {"max_gap": math.inf, "tokens": 0, "sequences": 0, "top1_agree": 0.0}
@@ -238,7 +270,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
     else:
         trc = tr.load(trace_dir)
         run = Run(arch=arch.of(config), mc=config["model_config"], qc=config["quant"],
-                  server=server, window=w, trace=trc, peak=peaks(device.device_kind))
+                  server=server, window=w, trace=trc, peak=peaks(device.device_kind),
+                  tp=cell.tp)
         for m in cell.per_layer:
             v = spec.load_reader(m.name)(run)
             if v is None:
@@ -263,6 +296,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
         "backlog_open": w.backlog[0], "backlog_close": w.backlog[1],
         "slow_steps": w.slow_steps, "dispatch_retries": w.counters["retries"],
         "drained": w.drained, "reference_s": ref_s,
+        "memory_in_use_at_close_bytes": in_use[0] if in_use else None,
         "reference_tokens": cmp["tokens"], "reference_sequences": cmp["sequences"],
         "reference_top1_agree": cmp["top1_agree"],
     }

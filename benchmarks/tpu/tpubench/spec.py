@@ -40,6 +40,12 @@ class Cell:
     end_to_end: List[Metric]
     per_layer: List[Metric]
 
+    @property
+    def tp(self) -> int:
+        """The tensor-parallel degree the configuration states (its
+        ``"parallel": {"tp": n}``; 1 where it states none)."""
+        return int(self.config.get("parallel", {}).get("tp", 1))
+
 
 def _metrics(entries, cell: str) -> List[Metric]:
     out = []
@@ -66,9 +72,13 @@ def load_cell(name: str, bench_path: Optional[str] = None) -> Cell:
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(os.path.join(ROOT, configs[w["config"]]["file"]))
     traffic = load_json(os.path.join(BENCH_DIR, "traffic", f"{w['traffic']}.json"))
-    return Cell(name=name, chips=int(w["chips"]), config=config, traffic=traffic,
+    cell = Cell(name=name, chips=int(w["chips"]), config=config, traffic=traffic,
                 end_to_end=_metrics(bench["end_to_end"], name),
                 per_layer=_metrics(bench["per_layer"], name))
+    if cell.chips != cell.tp:
+        raise ValueError(f"cell {name} asks for {cell.chips} chip(s), but its configuration "
+                         f"{w['config']} states tensor parallelism over {cell.tp}")
+    return cell
 
 
 def load_reader(metric: str) -> Callable:

@@ -11,7 +11,10 @@ packed 8 to a byte along k, least significant bit first.
 The weights live in a plain dict that belongs to the benchmark (the layout the
 float32 reference reads); which leaves it holds, and how the program's pytree
 wraps the same buffers, is the configuration's architecture module's
-(``tpubench.arch``).
+(``tpubench.arch``). A configuration that states tensor parallelism has them
+made already split over the chips, as its architecture's ``SPLIT`` says, so
+no chip ever holds a whole stacked matrix; threefry is partitionable, so the
+values do not depend on the layout.
 """
 
 from __future__ import annotations
@@ -21,6 +24,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from tpubench import arch
 
@@ -52,9 +57,51 @@ def _bcq_leaf(key, lead: Tuple[int, ...], k: int, o: int, q: int, g: int, sdt):
     return {"packed": packed, "scales": scales}
 
 
-def make_weights(config: dict, seed: int) -> dict:
-    """The whole model's weights, in one jitted call on the default device."""
-    return jax.jit(arch.of(config).weights_fn(config))(jax_key(seed, 0))
+def mesh(tp: int):
+    """The program's ``tp``-way tensor-parallel mesh over the first ``tp``
+    devices, or None for one chip."""
+    if tp == 1:
+        return None
+    from repro.parallel.tp import make_tp_mesh
+
+    return make_tp_mesh(tp)
+
+
+def shardings(config: dict, mesh, tree) -> dict:
+    """Where each leaf of the weights ``tree`` lives on ``mesh``: a linear's
+    packed planes and scales split along the output dim (column-parallel) or
+    the reduction dim (row-parallel), every other leaf whole on each chip."""
+    mod, axis = arch.of(config), mesh.axis_names[0]
+
+    def visit(path, leaf):
+        keys = [getattr(p, "key", None) for p in path]
+        dims = [None] * leaf.ndim
+        if keys[-1] in ("packed", "scales"):
+            dims[-1 if arch.split(mod, keys[-2]) == "col" else -2] = axis
+        return NamedSharding(mesh, P(*dims))
+
+    return jax.tree_util.tree_map_with_path(visit, tree)
+
+
+def make_weights(config: dict, seed: int, mesh=None) -> dict:
+    """The whole model's weights, in one jitted call: on the default device,
+    or split over ``mesh``."""
+    fn, key = arch.of(config).weights_fn(config), jax_key(seed, 0)
+    if mesh is None:
+        return jax.jit(fn)(key)
+    out = shardings(config, mesh, jax.eval_shape(fn, key))
+    return jax.jit(fn, out_shardings=out)(key)
+
+
+def to_host(tree, names):
+    """``tree`` with the leaves under the keys ``names`` copied to host
+    memory (JAX's CPU backend), all in one transfer."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    pick = [any(getattr(p, "key", None) in names for p in path) for path, _ in flat]
+    moved = iter(jax.device_put(jax.device_get([x for (_, x), m in zip(flat, pick) if m]),
+                                jax.devices("cpu")[0]))
+    return jax.tree_util.tree_unflatten(treedef, [next(moved) if m else x
+                                                  for (_, x), m in zip(flat, pick)])
 
 
 def program_params(weights: dict, config: dict):
